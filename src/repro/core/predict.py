@@ -17,6 +17,7 @@ bitwise-equal to per-program totals.
 from __future__ import annotations
 
 import functools
+import importlib.util
 import warnings
 from typing import Dict, List, Mapping, Optional, Sequence, Union
 
@@ -176,12 +177,16 @@ def _build_fused_kernel():
     per row with ``np.bincount``.  Only *elementwise* work runs under XLA
     — an IEEE multiply is the same bits everywhere — while the row
     reductions that define totals stay in numpy, so the fused path is
-    bitwise-identical to the plain one.  Runs under ``enable_x64`` (the
+    bitwise-identical to the plain one.  Runs under ``jax.enable_x64`` (the
     thread-local flag, not the global config) so float64 counts are not
-    silently downcast.
+    silently downcast, and on the host CPU backend even where an
+    accelerator is attached: the inputs are zero-copy views of host numpy
+    buffers, and only the CPU backend multiplies float64 with IEEE bits
+    (TPUs emulate it).
     """
     import jax
-    from jax.experimental import enable_x64
+
+    host = jax.devices("cpu")[0]
 
     @functools.partial(jax.jit, static_argnames=("direct_mode", "n_buckets"))
     def _kernel(c_mat, e_direct, e_pred, codes, mem, ids, *,
@@ -201,18 +206,16 @@ def _build_fused_kernel():
         return val, vfin, other, buckets
 
     def _view(a):
-        """Zero-copy numpy view of a CPU jax buffer (copy as last resort)."""
-        try:
-            return np.from_dlpack(a)
-        except Exception:
-            return np.asarray(a)
+        """Zero-copy numpy view of a host jax buffer."""
+        return np.from_dlpack(a)
 
     def _feed(a):
-        """Zero-copy numpy -> jax import (device_put copies; dlpack not)."""
+        """Zero-copy numpy -> host jax import (device_put copies; dlpack
+        not).  DLPack cannot export a read-only array: that one is copied."""
         try:
             return jax.dlpack.from_dlpack(a)
-        except Exception:
-            return a
+        except BufferError:
+            return jax.device_put(a, host)
 
     feeds: dict = {}
 
@@ -231,7 +234,7 @@ def _build_fused_kernel():
         return j
 
     def run(c_mat, e_direct, e_pred, codes, mem, direct_mode, n_buckets):
-        with enable_x64():
+        with jax.enable_x64(True):
             val, vfin, other, buckets = _kernel(
                 _feed(c_mat), _feed_cached(e_direct), _feed_cached(e_pred),
                 _feed_cached(codes), _feed(mem), _feed_cached(_COUNTER_IDS),
@@ -240,6 +243,7 @@ def _build_fused_kernel():
         # Predictions copy their own rows out below
         return _view(val), _view(vfin), _view(other), _view(buckets)
 
+    run.device = host
     return run
 
 
@@ -267,27 +271,33 @@ class TablePredictor:
 
     # -- fused (jitted) hot path --------------------------------------------
     def enable_fused(self) -> bool:
-        """Opt into the jitted hot path; True when jax is available.
+        """Opt into the jitted hot path; True when jax is installed.
 
         Bitwise-identical totals to the plain path (see
-        ``_build_fused_kernel``); processes without jax fall back
-        silently, so telemetry shard workers can flip this on untested.
+        ``_build_fused_kernel``); processes without jax keep the plain
+        path, so telemetry shard workers can flip this on untested.  Any
+        other failure to build the kernel is raised.
         """
         self._fused_requested = True
         return self._ensure_fused() is not None
+
+    @property
+    def fused_device(self):
+        """The JAX device the fused kernel runs on; None on the plain path."""
+        kern = self._ensure_fused()
+        return None if kern is None else kern.device
 
     def _ensure_fused(self):
         if not self._fused_requested or self._fused_kernel is False:
             return None
         if self._fused_kernel is None:
-            try:
-                self._fused_kernel = _build_fused_kernel()
-            except Exception as e:           # no jax in this process
-                warnings.warn(f"fused predict path unavailable ({e}); "
-                              f"using the plain numpy path", RuntimeWarning,
-                              stacklevel=3)
+            if importlib.util.find_spec("jax") is None:
+                warnings.warn("fused predict path unavailable (jax is not "
+                              "installed); using the plain numpy path",
+                              RuntimeWarning, stacklevel=3)
                 self._fused_kernel = False
                 return None
+            self._fused_kernel = _build_fused_kernel()
         return self._fused_kernel
 
     def warm(self) -> None:

@@ -1,57 +1,64 @@
 """GQA decode attention — Pallas TPU kernel.
 
-One program per (batch, kv-head): the query group [G, D] stays in VREGs,
-the KV cache streams through VMEM in [BK, D] blocks, invalid (beyond
-``length``) positions are masked.  This is the HBM-bandwidth-bound hot loop
-of serving (decode_32k / long_500k shapes): arithmetic intensity ~G MACs
-per cache byte, so the tiling goal is purely streaming efficiency.
+One program per (batch, kv-head) sweeps the cache along the innermost grid
+axis: the query group [G, D] stays resident in VMEM, the KV cache streams
+through VMEM in [BK, D] blocks (heads laid out ahead of (seq, D)), invalid
+(beyond ``length``) positions are masked, and the running softmax lives in
+f32 VMEM scratch.  The valid lengths are prefetched into SMEM.  This is the
+HBM-bandwidth-bound hot loop of serving (decode_32k / long_500k shapes):
+arithmetic intensity ~G MACs per cache byte, so the tiling goal is purely
+streaming efficiency.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int):
-    g, d = q_ref.shape[-2], q_ref.shape[-1]
-    s = k_ref.shape[1]
-    length = len_ref[0]
-    q = q_ref[0, 0, :, :].astype(jnp.float32) / math.sqrt(d)    # [G, D]
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                   l_ref, *, block_k: int):
+    d = q_ref.shape[-1]
+    b_idx, k_idx = pl.program_id(0), pl.program_id(2)
 
-    def body(i, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, pl.dslice(i * block_k, block_k), 0, :]     # [BK, D]
-        v = v_ref[0, pl.dslice(i * block_k, block_k), 0, :]
-        scores = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # [G, BK]
-        k_pos = (i * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-        scores = jnp.where(k_pos < length, scores, NEG_INF)
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
+    @pl.when(k_idx == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    acc0 = jnp.zeros((g, d), jnp.float32)
-    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    n_k = s // block_k
-    acc, m, l = jax.lax.fori_loop(0, n_k, body, (acc0, m0, l0))
-    o_ref[0, 0, :, :] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    length = len_ref[b_idx]
+    q = q_ref[0, 0].astype(jnp.float32) / math.sqrt(d)          # [G, D]
+    k = k_ref[0, 0]                                             # [BK, D]
+    v = v_ref[0, 0]
+    scores = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # [G, BK]
+    k_pos = (k_idx * block_k
+             + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+    scores = jnp.where(k_pos < length, scores, NEG_INF)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_cur = jnp.max(scores, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+    @pl.when(k_idx == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *,
@@ -72,18 +79,24 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
         v_cache = jnp.pad(v_cache, widths)
         s = s_pad
     qg = q.reshape(b, kvh, g, d)
-    grid = (b, kvh)
+    kc = k_cache.transpose(0, 2, 1, 3)                          # [B,KV,S,D]
+    vc = v_cache.transpose(0, 2, 1, 3)
+    q_spec = pl.BlockSpec((1, 1, g, d), lambda b_, kv, j, lens: (b_, kv, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda b_, kv, j, lens: (b_, kv, j, 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, kv: (b_,)),
-            pl.BlockSpec((1, 1, g, d), lambda b_, kv: (b_, kv, 0, 0)),
-            pl.BlockSpec((1, s, 1, d), lambda b_, kv: (b_, 0, kv, 0)),
-            pl.BlockSpec((1, s, 1, d), lambda b_, kv: (b_, 0, kv, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, kv: (b_, kv, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kvh, s // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((g, d), jnp.float32),
+                            pltpu.VMEM((g, 1), jnp.float32),
+                            pltpu.VMEM((g, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, kc, vc)
     return out.reshape(b, h, d)

@@ -19,17 +19,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_mesh(shape, axes):
     """Arbitrary mesh helper (smoke tests, elastic re-meshes).
 
-    Version-tolerant across the ``jax.sharding.AxisType`` API drift (same
-    posture as ``parallel.sharding.abstract_mesh``): newer JAX wants every
-    axis explicitly typed ``Auto`` for shard_map interop; older JAX has no
-    ``AxisType`` and every ``make_mesh`` axis is implicitly auto.
+    Every axis is typed ``Auto``, so GSPMD propagates shardings and
+    ``shard_map`` bodies interoperate with the sharding rules.
     """
     shape, axes = tuple(shape), tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    except TypeError:      # AxisType exists but make_mesh predates axis_types
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

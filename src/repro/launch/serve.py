@@ -27,6 +27,7 @@ import numpy as np
 from repro import configs as cfgs
 from repro.api import EnergyModel
 from repro.core.opcount import count_fn
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_mod
 from repro.serve.scheduler import EnergyPolicy, Request
 from repro.serve.step import make_prefill_step, make_serve_step
@@ -211,6 +212,7 @@ def main(argv=None) -> int:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed for the chaos plan (same seed = same faults)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     report, _ = run(args.arch, smoke=args.smoke, tenants=args.tenants,
                     requests=args.requests, prompt_len=args.prompt_len,
                     max_new=args.max_new, max_batch=args.max_batch,

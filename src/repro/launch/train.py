@@ -25,6 +25,7 @@ from repro.api import EnergyModel
 from repro.configs.base import ShapeSpec
 from repro.core.opcount import count_fn
 from repro.data.pipeline import DataConfig, model_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import model as model_mod
 from repro.parallel import sharding as sh
@@ -143,7 +144,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
         if ckpt_dir and (step + 1) % ckpt_every == 0:
             ckpt_mod.save(ckpt_dir, step + 1, state)
         if verbose:
-            print(f"[train] step {step} loss={loss:.4f} ({dt*1e3:.0f}ms)")
+            print(f"[train] step {step} loss={loss:.4f} ({dt * 1e3:.3f} ms)")
     if monitor is not None and monitor.live.steps_registered:
         if plane is not None:
             monitor.live.start()
@@ -209,6 +210,7 @@ def main(argv=None) -> int:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed for the chaos plan (same seed = same faults)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     _, losses, _ = run(args.arch, smoke=args.smoke, steps=args.steps,
                        seq_len=args.seq_len, global_batch=args.global_batch,
                        ckpt_dir=args.ckpt_dir, fail_at=args.fail_at,
@@ -222,10 +224,11 @@ def main(argv=None) -> int:
                        telemetry_shards=args.telemetry_shards or None,
                        chaos_profile=args.chaos_profile,
                        chaos_seed=args.chaos_seed)
-    ok = np.isfinite(losses).all() and losses[-1] < losses[0]
+    finite = bool(np.isfinite(losses).all())
+    ok = finite and losses[-1] < losses[0]
     print(f"[train] loss {losses[0]:.4f} -> {losses[-1]:.4f} "
           f"({'improved' if ok else 'check'})")
-    return 0
+    return 0 if finite else 1
 
 
 if __name__ == "__main__":

@@ -14,7 +14,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 CHUNK = 1024
 
@@ -79,7 +78,7 @@ def make_compressor(mesh: Mesh, axis_name: str = "data"):
             spec = P(*([None] * g.ndim))
 
             @functools.partial(
-                shard_map, mesh=mesh, in_specs=spec, out_specs=spec)
+                jax.shard_map, mesh=mesh, in_specs=spec, out_specs=spec)
             def run(gl):
                 return compressed_psum(gl / mesh.shape[axis_name], axis_name)
             return run(g)

@@ -13,24 +13,11 @@ pipeline hops cross DCN with only [microbatch, d_model]-sized tensors.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                     # jax >= 0.8
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
@@ -81,16 +68,13 @@ def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
 
         held0 = xs_local[0]
         # the carry becomes stage-varying after the first ppermute
-        try:
-            held0 = jax.lax.pcast(held0, (axis,), to="varying")
-            outputs = jax.lax.pcast(outputs, (axis,), to="varying")
-        except AttributeError:     # older jax without vma typing
-            pass
+        held0 = jax.lax.pcast(held0, (axis,), to="varying")
+        outputs = jax.lax.pcast(outputs, (axis,), to="varying")
         _, outputs = jax.lax.fori_loop(0, ticks, tick, (held0, outputs))
         return outputs[None]      # [1, M, ...] per stage
 
-    fn = shard_map(per_stage, mesh,
-                   in_specs=(P(axis), P()),       # params sharded by stage
-                   out_specs=P(axis))
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(P(axis), P()),   # params sharded by stage
+                       out_specs=P(axis))
     outs = fn(stage_params, microbatches)         # [S, M, ...]
     return outs[-1]

@@ -42,21 +42,15 @@ BATCH_AXES = ("pod", "data")
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh for rule evaluation, across JAX API generations.
+    """Device-free mesh for rule evaluation.
 
-    ``jax.sharding.AbstractMesh`` changed signature: newer JAX takes
-    ``(axis_sizes, axis_names)``, older JAX a tuple of ``(name, size)``
-    pairs.  The sharding rules only consume ``mesh.shape`` /
-    ``mesh.axis_names``, which both generations provide.
+    The sharding rules only consume ``mesh.shape`` / ``mesh.axis_names``.
     """
     sizes = tuple(int(s) for s in axis_sizes)
     names = tuple(axis_names)
     if len(sizes) != len(names):
         raise ValueError(f"{len(sizes)} axis sizes vs {len(names)} names")
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return jax.sharding.AbstractMesh(sizes, names)
 
 
 def fsdp_axes(mesh: Mesh) -> Tuple[str, ...]:

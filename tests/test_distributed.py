@@ -25,7 +25,7 @@ def test_compressed_psum_close_to_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.parallel.compression import compressed_psum
 
     from repro.launch.mesh import make_mesh
@@ -50,7 +50,7 @@ def test_error_feedback_converges():
     _run("""
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.parallel.compression import make_error_feedback
 
     from repro.launch.mesh import make_mesh
@@ -191,7 +191,7 @@ def test_opcount_shard_map_collectives():
     _run("""
     import jax, jax.numpy as jnp, functools
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.opcount import count_fn
 
     from repro.launch.mesh import make_mesh

@@ -1,0 +1,112 @@
+"""Compiles for a described (not attached) TPU v5e chip: what the chip's
+compiler refuses — block shapes off the (8, 128) tiling, more fast memory
+than a kernel may use, a step that does not fit the device — fails here
+without a chip.  Nothing runs, so these say nothing about results or times.
+
+The topology is described inside a module fixture only: describing it
+loads the TPU library, which one process at a time may hold, so it must
+never happen while a module is imported or collected.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs as cfgs
+from repro.kernels import ops
+from repro.models import model as model_mod
+from repro.serve.step import make_serve_step
+from repro.train import optimizer as opt_mod
+from repro.train.step import init_state, make_train_step
+
+HBM_BYTES = 15.75 * 2 ** 30          # what the compiler lets one v5e use
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_flash_attention_compiles_at_qwen2_widths(one_chip):
+    x = _sds(one_chip, (1, 4096, 14, 64))
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
+             x, x, x)
+
+
+@pytest.mark.parametrize("cache", [32768, 131072])
+def test_decode_attention_compiles_over_long_cache(one_chip, cache):
+    kv = _sds(one_chip, (2, cache, 2, 64))
+    _compile(lambda q, k, v, n: ops.decode_attention(q, k, v, n,
+                                                     interpret=False),
+             _sds(one_chip, (2, 14, 64)), kv, kv,
+             _sds(one_chip, (2,), jnp.int32))
+
+
+def test_ssd_compiles_at_mamba2_widths(one_chip):
+    s, h, p, n = 4096, 80, 64, 128
+    f32 = jnp.float32
+    _compile(lambda x, dt, a, b, c: ops.ssd_chunked(x, dt, a, b, c, chunk=256,
+                                                    interpret=False),
+             _sds(one_chip, (1, s, h, p)), _sds(one_chip, (1, s, h), f32),
+             _sds(one_chip, (h,), f32), _sds(one_chip, (1, s, n)),
+             _sds(one_chip, (1, s, n)))
+
+
+def test_qwen2_train_step_fits_one_chip(one_chip):
+    """The full-width qwen2-0.5b step that ``launch/train.run`` jits, at
+    4 x 1024: compiled, and its arguments, outputs and temporaries fit."""
+    cfg = cfgs.get_config("qwen2-0.5b")
+    opt_cfg = opt_mod.OptConfig(total_steps=4, warmup_steps=2,
+                                mv_dtype=cfg.optimizer_dtype,
+                                master_fp32=cfg.optimizer_dtype == "float32")
+    state = jax.eval_shape(lambda k: init_state(cfg, opt_cfg, k),
+                           jax.random.PRNGKey(0))
+    state = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), state)
+    tokens = _sds(one_chip, (4, 1024), jnp.int32)
+    step = jax.jit(make_train_step(cfg, opt_cfg), donate_argnums=(0,))
+    mem = step.lower(state, {"tokens": tokens, "targets": tokens}) \
+        .compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES, mem
+
+
+def test_qwen2_decode_step_compiles(one_chip):
+    """The full-width decode step ``serve/step.greedy_generate`` jits."""
+    cfg = cfgs.get_config("qwen2-0.5b")
+    on_chip = lambda a: _sds(one_chip, a.shape, a.dtype)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: model_mod.init_params(cfg, k), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model_mod.init_cache(cfg, 2, 32)))
+    mem = jax.jit(make_serve_step(cfg)).lower(
+        params, cache, _sds(one_chip, (2, 1), jnp.int32)) \
+        .compile().memory_analysis()
+    assert 0 < mem.argument_size_in_bytes < HBM_BYTES, mem
