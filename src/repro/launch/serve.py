@@ -27,7 +27,7 @@ import numpy as np
 from repro import configs as cfgs
 from repro.api import EnergyModel
 from repro.core.opcount import count_fn
-from repro.launch.compile_cache import use_compile_cache
+from repro.launch.compile_cache import compile_line, use_compile_cache
 from repro.models import model as model_mod
 from repro.serve.scheduler import EnergyPolicy, Request
 from repro.serve.step import make_prefill_step, make_serve_step
@@ -223,6 +223,7 @@ def main(argv=None) -> int:
                     telemetry_shards=args.telemetry_shards or None,
                     chaos_profile=args.chaos_profile,
                     chaos_seed=args.chaos_seed)
+    print(compile_line())
     assert len(report.requests) == args.requests
     return 0
 

@@ -25,7 +25,7 @@ from repro.api import EnergyModel
 from repro.configs.base import ShapeSpec
 from repro.core.opcount import count_fn
 from repro.data.pipeline import DataConfig, model_batch
-from repro.launch.compile_cache import use_compile_cache
+from repro.launch.compile_cache import compile_line, use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import model as model_mod
 from repro.parallel import sharding as sh
@@ -224,6 +224,7 @@ def main(argv=None) -> int:
                        telemetry_shards=args.telemetry_shards or None,
                        chaos_profile=args.chaos_profile,
                        chaos_seed=args.chaos_seed)
+    print(compile_line())
     finite = bool(np.isfinite(losses).all())
     ok = finite and losses[-1] < losses[0]
     print(f"[train] loss {losses[0]:.4f} -> {losses[-1]:.4f} "

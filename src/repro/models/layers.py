@@ -166,62 +166,66 @@ def attention(x, p, cfg: ModelConfig, positions, *, kv_cache=None,
     ``attn_fn``: optional fused kernel (flash attention) for the
     no-cache full-sequence path.
     """
-    b, s, d_model = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    if "bq" in p:
-        q = q + p["bq"]
-    if kv_override is not None:
-        k, v = kv_override
-        k_pos = jnp.broadcast_to(jnp.arange(k.shape[1], dtype=jnp.int32)[None],
-                                 (b, k.shape[1]))
+    with jax.named_scope("attn"):
+        b, s, d_model = x.shape
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        if "bq" in p:
+            q = q + p["bq"]
+        if kv_override is not None:
+            k, v = kv_override
+            k_pos = jnp.broadcast_to(
+                jnp.arange(k.shape[1], dtype=jnp.int32)[None], (b, k.shape[1]))
+            q = apply_rope(q, positions, cfg.rope_theta, mrope_sections)
+            new_cache = kv_cache
+            # cross attention: no causal mask
+            kvh = k.shape[2]
+            group = cfg.n_heads // kvh
+            qg = q.reshape(b, s, kvh, group, q.shape[-1])
+            scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+            scores = scores / math.sqrt(q.shape[-1])
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+            out = out.reshape(b, s, cfg.n_heads, -1)
+            return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+        if "bk" in p:
+            k = k + p["bk"]
+            v = v + p["bv"]
         q = apply_rope(q, positions, cfg.rope_theta, mrope_sections)
-        new_cache = kv_cache
-        # cross attention: no causal mask
-        kvh = k.shape[2]
+        k = apply_rope(k, positions, cfg.rope_theta, mrope_sections)
+
+        if kv_cache is not None:
+            with jax.named_scope("kv_cache"):
+                ck, cv = kv_cache["k"], kv_cache["v"]
+                ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                                  (0, cache_pos, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                                  (0, cache_pos, 0, 0))
+                new_cache = {"k": ck, "v": cv}
+                k_full, v_full = ck, cv
+                k_pos = jnp.broadcast_to(
+                    jnp.arange(ck.shape[1], dtype=jnp.int32)[None],
+                    (b, ck.shape[1]))
+                valid = k_pos <= (cache_pos + s - 1)
+                k_pos = jnp.where(valid, k_pos, jnp.iinfo(jnp.int32).max)
+        else:
+            new_cache = None
+            k_full, v_full = k, v
+            k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
+                                     (b, s))
+            if attn_fn is not None:
+                out = attn_fn(q, k, v, cfg)
+                return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
+
+        probs = _attn_weights(q, k_full, cfg, positions if positions.ndim == 2
+                              else positions[0], k_pos, window, causal=causal)
+        kvh = k_full.shape[2]
         group = cfg.n_heads // kvh
-        qg = q.reshape(b, s, kvh, group, q.shape[-1])
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-        scores = scores / math.sqrt(q.shape[-1])
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(x.dtype), v_full)
         out = out.reshape(b, s, cfg.n_heads, -1)
         return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
-
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if "bk" in p:
-        k = k + p["bk"]
-        v = v + p["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta, mrope_sections)
-    k = apply_rope(k, positions, cfg.rope_theta, mrope_sections)
-
-    if kv_cache is not None:
-        ck, cv = kv_cache["k"], kv_cache["v"]
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cache_pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cache_pos, 0, 0))
-        new_cache = {"k": ck, "v": cv}
-        k_full, v_full = ck, cv
-        k_pos = jnp.broadcast_to(
-            jnp.arange(ck.shape[1], dtype=jnp.int32)[None], (b, ck.shape[1]))
-        valid = k_pos <= (cache_pos + s - 1)
-        k_pos = jnp.where(valid, k_pos, jnp.iinfo(jnp.int32).max)
-    else:
-        new_cache = None
-        k_full, v_full = k, v
-        k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-        if attn_fn is not None:
-            out = attn_fn(q, k, v, cfg)
-            return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
-
-    probs = _attn_weights(q, k_full, cfg, positions if positions.ndim == 2
-                          else positions[0], k_pos, window, causal=causal)
-    kvh = k_full.shape[2]
-    group = cfg.n_heads // kvh
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(x.dtype), v_full)
-    out = out.reshape(b, s, cfg.n_heads, -1)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -317,12 +321,13 @@ def mlp_specs(cfg: ModelConfig, d_ff: Optional[int] = None,
 
 def mlp(x, p, cfg: ModelConfig):
     act = jax.nn.silu if cfg.mlp_act == "silu" else jax.nn.gelu
-    if "w_gate" in p:
-        h = act(x @ p["w_gate"]) * (x @ p["w_in"])
-    else:
-        h = act(x @ p["w_in"])
-    h = constrain(h, [BATCH] + [None] * (h.ndim - 2) + [MODEL])
-    return h @ p["w_out"]
+    with jax.named_scope("mlp"):
+        if "w_gate" in p:
+            h = act(x @ p["w_gate"]) * (x @ p["w_in"])
+        else:
+            h = act(x @ p["w_in"])
+        h = constrain(h, [BATCH] + [None] * (h.ndim - 2) + [MODEL])
+        return h @ p["w_out"]
 
 
 # ---------------------------------------------------------------------------
@@ -338,15 +343,16 @@ def embed_specs(cfg: ModelConfig) -> Dict[str, PSpec]:
 
 
 def embed(tokens, p, cfg: ModelConfig):
-    x = jnp.take(p["tok"], tokens, axis=0)
-    if cfg.family == "encdec" or cfg.mlp_act == "gelu":
-        x = x * math.sqrt(cfg.d_model)       # gemma/whisper-style scaling
-    return x.astype(cfg.activation_dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(p["tok"], tokens, axis=0)
+        if cfg.family == "encdec" or cfg.mlp_act == "gelu":
+            x = x * math.sqrt(cfg.d_model)   # gemma/whisper-style scaling
+        return x.astype(cfg.activation_dtype)
 
 
 def lm_head(x, p, cfg: ModelConfig):
-    w = p["tok"].T if cfg.tie_embeddings else p["head"]
-    logits = x @ w.astype(x.dtype)
-    logits = constrain(logits, [BATCH, None, MODEL])
-    logits = _softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
-    return logits
+    with jax.named_scope("lm_head"):
+        w = p["tok"].T if cfg.tie_embeddings else p["head"]
+        logits = x @ w.astype(x.dtype)
+        logits = constrain(logits, [BATCH, None, MODEL])
+        return _softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)

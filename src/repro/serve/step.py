@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_mod
+from repro.spans import span
 
 
 def make_prefill_step(cfg: ModelConfig, attn_fn=None):
@@ -27,7 +28,8 @@ def make_serve_step(cfg: ModelConfig, attn_fn=None):
     def serve_step(params, cache, tokens):
         logits, cache = model_mod.decode_step(params, cache, tokens, cfg,
                                               attn_fn=attn_fn)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok[:, None], cache
     return serve_step
 
@@ -49,18 +51,29 @@ def jitted_serve_step(cfg: ModelConfig, attn_fn=None):
 
 def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                     max_new: int, max_seq: int, attn_fn=None):
-    """Greedy decode loop (example/serving driver path)."""
-    b = prompt.shape[0]
-    cache = model_mod.init_cache(cfg, b, max_seq)
-    step = jitted_serve_step(cfg, attn_fn)
-    # teacher-force the prompt through the decode path
-    tok = prompt[:, :1]
-    out = [tok]
-    for i in range(prompt.shape[1] - 1):
-        _, cache = step(params, cache, prompt[:, i:i + 1])
-        out.append(prompt[:, i + 1:i + 2])
-    tok = prompt[:, -1:]
-    for _ in range(max_new):
-        tok, cache = step(params, cache, tok)
-        out.append(tok)
-    return jnp.concatenate(out, axis=1)
+    """Greedy decode loop, as the examples and serving run it.
+
+    Each launch runs under a host span (``repro.spans``): the prompt's
+    teacher-forced launches under ``serve.prompt_step``, the generating
+    ones under ``serve.decode_step``, all inside ``serve.generate``.
+    """
+    b, p = prompt.shape
+    with span("serve.generate", batch=b, prompt_len=p, max_new=max_new,
+              max_seq=max_seq):
+        with span("serve.init_cache"):
+            cache = model_mod.init_cache(cfg, b, max_seq)
+        step = jitted_serve_step(cfg, attn_fn)
+        # teacher-force the prompt through the decode path
+        tok = prompt[:, :1]
+        out = [tok]
+        for i in range(p - 1):
+            with span("serve.prompt_step"):
+                _, cache = step(params, cache, prompt[:, i:i + 1])
+                out.append(prompt[:, i + 1:i + 2])
+        tok = prompt[:, -1:]
+        for _ in range(max_new):
+            with span("serve.decode_step"):
+                tok, cache = step(params, cache, tok)
+            out.append(tok)
+        with span("serve.concat"):
+            return jnp.concatenate(out, axis=1)
