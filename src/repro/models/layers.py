@@ -12,8 +12,15 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.layout import Layout, with_layout_constraint
+
 from repro.configs.base import ModelConfig
 from repro.parallel.act_sharding import BATCH, MODEL, constrain
+
+# The TPU's lane width.  An attention cache packs KV heads into rows of this
+# many lanes (``kv_pack``) and holds a whole number of tiles of this many
+# positions (``transformer.cache_capacity``).
+CACHE_TILE = 128
 
 
 # ---------------------------------------------------------------------------
@@ -135,16 +142,12 @@ def _softcap(x, cap: Optional[float]):
     return jnp.tanh(x / cap) * cap
 
 
-def _attn_weights(q, k, cfg: ModelConfig, q_pos, k_pos, window, causal=True):
-    """q [B,Sq,H,D] k [B,Sk,KV,D] -> probs [B,KV,G,Sq,Sk] (f32)."""
-    b, sq, h, d = q.shape
-    kvh = k.shape[2]
-    group = h // kvh
-    qg = q.reshape(b, sq, kvh, group, d)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+def _attn_weights(scores, cfg: ModelConfig, q_pos, k_pos, window,
+                  causal=True):
+    """scores q.k [B,KV,G,Sq,Sk] (f32) -> probs [B,KV,G,Sq,Sk] (f32)."""
     # scores [B,KV,G,Sq,Sk]: model axis on kv-heads, else q-groups, else Sq
     scores = constrain(scores, [BATCH, MODEL, MODEL, MODEL, None])
-    scores = scores / math.sqrt(d)
+    scores = scores / math.sqrt(cfg.head_dim_)
     scores = _softcap(scores, cfg.attn_logit_softcap)
     mask = k_pos[:, None, :] <= q_pos[:, :, None] if causal else \
         (k_pos[:, None, :] < jnp.iinfo(jnp.int32).max)        # [B,Sq,Sk]
@@ -156,12 +159,102 @@ def _attn_weights(q, k, cfg: ModelConfig, q_pos, k_pos, window, causal=True):
     return probs     # [B,KV,G,Sq,Sk]
 
 
+def kv_pack(cfg: ModelConfig) -> int:
+    """KV heads that share one row of the decode cache: as many whole heads
+    as fit in CACHE_TILE lanes, a divisor of the KV heads."""
+    return math.gcd(cfg.n_kv_heads, max(1, CACHE_TILE // cfg.head_dim_))
+
+
+def kv_cache_row(cfg: ModelConfig, capacity: int) -> Tuple[int, int, int]:
+    """One layer's attention cache after its batch axis, as ``attention``
+    stores it: [KV/pack, capacity, pack*D]."""
+    pack = kv_pack(cfg)
+    return (cfg.n_kv_heads // pack, capacity, pack * cfg.head_dim_)
+
+
+def _pack_heads(x, pack: int):
+    """[B,S,KV,D] -> [B,KV/pack,S,pack*D]: ``pack`` heads side by side."""
+    b, s, kv, d = x.shape
+    return x.reshape(b, s, kv // pack, pack * d).swapaxes(1, 2)
+
+
+def _packed_scores(q, k, pack: int):
+    """q [B,Sq,H,D] against a packed k [B,P,Sk,pack*D] -> [B,KV,G,Sq,Sk].
+
+    Each query head is laid in the lanes of its own KV head and is zero in
+    the others of its pack (block-diagonal), so one dot over whole rows
+    scores every head of a pack: ``pack`` times the FLOPs, and the cache
+    is read as it is stored.
+    """
+    b, sq, h, d = q.shape
+    p = k.shape[1]
+    g = h // (p * pack)
+    eye = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+    qb = (q.reshape(b, sq, p, pack, g, 1, d) * eye).reshape(
+        b, sq, p, pack * g, pack * d)
+    scores = jnp.einsum("bqpmj,bpsj->bpmqs", qb, k).astype(jnp.float32)
+    return scores.reshape(b, p * pack, g, sq, k.shape[2])
+
+
+def _packed_values(probs, v, pack: int):
+    """probs [B,KV,G,Sq,Sk] over a packed v [B,P,Sk,pack*D] -> [B,Sq,H,D]:
+    each head keeps the lanes of its own KV head."""
+    b, kv, g, sq, sk = probs.shape
+    p = v.shape[1]
+    d = v.shape[-1] // pack
+    out = jnp.einsum("bpmqs,bpsj->bqpmj",
+                     probs.reshape(b, p, pack * g, sq, sk), v)
+    out = jnp.einsum("bqpigjd,ij->bqpigd",
+                     out.reshape(b, sq, p, pack, g, pack, d),
+                     jnp.eye(pack, dtype=out.dtype))
+    return out.reshape(b, sq, kv * g, d)
+
+
+def update_cache(cache, new, layer, pos, seq_axis):
+    """Write ``new`` into the stacked cache [L, ...] of every layer at
+    position ``pos`` of ``seq_axis`` (an axis of one layer's slice) in layer
+    ``layer``, and read back that layer's slice.  Returns (cache, slice).
+
+    Only the new positions are written: inside the decode step's layer
+    scan, where the stacked cache is a carry, the update is in place.  The
+    cache keeps the layout the TPU gives it by default, which the attention
+    dots read without a copy: its last axis minor when that fills whole
+    128-lane tiles, else its sequence axis.  Unpinned, the loop would take
+    the layout of the small update and relayout the whole cache on entry,
+    on exit and for each layer's read (v5e compile).
+    """
+    at = [layer] + [0] * new.ndim
+    at[1 + seq_axis] = pos
+    cache = jax.lax.dynamic_update_slice(cache, new[None].astype(cache.dtype),
+                                         at)
+    order = list(range(cache.ndim))
+    if cache.shape[-1] % CACHE_TILE:
+        order.append(order.pop(1 + seq_axis))
+    cache = with_layout_constraint(cache, Layout(tuple(order)))
+    return cache, jax.lax.dynamic_index_in_dim(cache, layer, 0, False)
+
+
+def _cache_positions(b, smax, cache_pos, s):
+    """Key positions [B,Smax] of a cache filled up to ``cache_pos + s``;
+    the unfilled tail reads as int32 max, past every causal mask."""
+    k_pos = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32)[None],
+                             (b, smax))
+    return jnp.where(k_pos <= (cache_pos + s - 1), k_pos,
+                     jnp.iinfo(jnp.int32).max)
+
+
 def attention(x, p, cfg: ModelConfig, positions, *, kv_cache=None,
-              cache_pos=None, window=None, mrope_sections=None,
-              kv_override=None, attn_fn=None, causal=True):
+              cache_pos=None, cache_layer=None, window=None,
+              mrope_sections=None, kv_override=None, attn_fn=None,
+              causal=True):
     """Returns (out [B,S,d], new_kv_cache).
 
-    ``kv_cache``: dict(k=[B,Smax,KV,D], v=...) updated at ``cache_pos``.
+    ``kv_cache``: the stacked cache of every layer, dict(k=[L,B,P,Smax,W],
+    v=...) with ``kv_pack(cfg)`` KV heads side by side in each row of W
+    lanes (P = KV / pack, W = pack * D).  This layer's keys and values are
+    written at ``cache_pos`` in layer ``cache_layer`` (``update_cache``),
+    attention reads that layer's slice as stored, and the whole cache is
+    returned.
     ``kv_override``: precomputed (k, v) for cross-attention.
     ``attn_fn``: optional fused kernel (flash attention) for the
     no-cache full-sequence path.
@@ -196,36 +289,33 @@ def attention(x, p, cfg: ModelConfig, positions, *, kv_cache=None,
         q = apply_rope(q, positions, cfg.rope_theta, mrope_sections)
         k = apply_rope(k, positions, cfg.rope_theta, mrope_sections)
 
+        q_pos = positions if positions.ndim == 2 else positions[0]
         if kv_cache is not None:
+            pack = kv_pack(cfg)
             with jax.named_scope("kv_cache"):
-                ck, cv = kv_cache["k"], kv_cache["v"]
-                ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                                  (0, cache_pos, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                                  (0, cache_pos, 0, 0))
-                new_cache = {"k": ck, "v": cv}
-                k_full, v_full = ck, cv
-                k_pos = jnp.broadcast_to(
-                    jnp.arange(ck.shape[1], dtype=jnp.int32)[None],
-                    (b, ck.shape[1]))
-                valid = k_pos <= (cache_pos + s - 1)
-                k_pos = jnp.where(valid, k_pos, jnp.iinfo(jnp.int32).max)
-        else:
-            new_cache = None
-            k_full, v_full = k, v
-            k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
-                                     (b, s))
-            if attn_fn is not None:
-                out = attn_fn(q, k, v, cfg)
-                return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
+                ck, k_full = update_cache(kv_cache["k"], _pack_heads(k, pack),
+                                          cache_layer, cache_pos, 2)
+                cv, v_full = update_cache(kv_cache["v"], _pack_heads(v, pack),
+                                          cache_layer, cache_pos, 2)
+                k_pos = _cache_positions(b, k_full.shape[2], cache_pos, s)
+            probs = _attn_weights(_packed_scores(q, k_full, pack), cfg, q_pos,
+                                  k_pos, window, causal=causal)
+            out = _packed_values(probs.astype(x.dtype), v_full, pack)
+            return (jnp.einsum("bshk,hkd->bsd", out, p["wo"]),
+                    {"k": ck, "v": cv})
 
-        probs = _attn_weights(q, k_full, cfg, positions if positions.ndim == 2
-                              else positions[0], k_pos, window, causal=causal)
-        kvh = k_full.shape[2]
-        group = cfg.n_heads // kvh
-        out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(x.dtype), v_full)
+        if attn_fn is not None:
+            out = attn_fn(q, k, v, cfg)
+            return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
+        kvh = k.shape[2]
+        qg = q.reshape(b, s, kvh, cfg.n_heads // kvh, q.shape[-1])
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+        k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+        probs = _attn_weights(scores, cfg, q_pos, k_pos, window,
+                              causal=causal)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(x.dtype), v)
         out = out.reshape(b, s, cfg.n_heads, -1)
-        return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), None
 
 
 # ---------------------------------------------------------------------------
@@ -250,8 +340,12 @@ def mla_specs(cfg: ModelConfig) -> Dict[str, PSpec]:
 
 
 def mla_attention(x, p, cfg: ModelConfig, positions, *, kv_cache=None,
-                  cache_pos=None):
-    """MLA: the cache stores the compressed latent + rope key only."""
+                  cache_pos=None, cache_layer=None):
+    """MLA: the cache stores the compressed latent + rope key only.
+
+    ``kv_cache``: the stacked dict(latent=[L,B,Smax,R], k_rope=[L,B,Smax,rd]),
+    written and read as ``attention``'s.
+    """
     b, s, _ = x.shape
     h = cfg.n_heads
     nd, rd, kvr = cfg.nope_head_dim, cfg.rope_head_dim, cfg.kv_lora_rank
@@ -267,19 +361,13 @@ def mla_attention(x, p, cfg: ModelConfig, positions, *, kv_cache=None,
     k_rope = k_rope[..., 0, :]                            # [B,S,rd]
 
     if kv_cache is not None:
-        lat_c = jax.lax.dynamic_update_slice(
-            kv_cache["latent"], latent.astype(kv_cache["latent"].dtype),
-            (0, cache_pos, 0))
-        kr_c = jax.lax.dynamic_update_slice(
-            kv_cache["k_rope"], k_rope.astype(kv_cache["k_rope"].dtype),
-            (0, cache_pos, 0))
-        new_cache = {"latent": lat_c, "k_rope": kr_c}
-        latent_full, k_rope_full = lat_c, kr_c
-        smax = lat_c.shape[1]
-        k_pos = jnp.broadcast_to(jnp.arange(smax, dtype=jnp.int32)[None],
-                                 (b, smax))
-        k_pos = jnp.where(k_pos <= (cache_pos + s - 1), k_pos,
-                          jnp.iinfo(jnp.int32).max)
+        with jax.named_scope("kv_cache"):
+            lat_c, latent_full = update_cache(kv_cache["latent"], latent,
+                                              cache_layer, cache_pos, 1)
+            kr_c, k_rope_full = update_cache(kv_cache["k_rope"], k_rope,
+                                             cache_layer, cache_pos, 1)
+            new_cache = {"latent": lat_c, "k_rope": kr_c}
+            k_pos = _cache_positions(b, latent_full.shape[1], cache_pos, s)
     else:
         new_cache = None
         latent_full, k_rope_full = latent, k_rope

@@ -20,8 +20,9 @@ from repro.configs.base import ModelConfig
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.parallel.act_sharding import BATCH, MODEL, constrain
-from repro.models.layers import (PSpec, attention, attention_specs, embed,
-                                 embed_specs, lm_head, mla_attention,
+from repro.models.layers import (CACHE_TILE, PSpec, attention,
+                                 attention_specs, embed, embed_specs,
+                                 kv_cache_row, lm_head, mla_attention,
                                  mla_specs, mlp, mlp_specs, rms_norm)
 
 BIG_WINDOW = 1 << 30
@@ -115,9 +116,21 @@ def n_shared_apps(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 # KV / state caches.
 # ---------------------------------------------------------------------------
+def cache_capacity(max_seq: int) -> int:
+    """Positions a cache holds for ``max_seq``: whole tiles of CACHE_TILE,
+    so that the TPU can lay the sequence axis minor (``update_cache``)."""
+    return -(-max_seq // CACHE_TILE) * CACHE_TILE
+
+
 def init_cache_specs(cfg: ModelConfig, batch: int, max_seq: int):
-    """ShapeDtypeStruct tree for the decode cache."""
+    """ShapeDtypeStruct tree for the decode cache.
+
+    Every cache that grows by a position holds ``cache_capacity(max_seq)``
+    positions; the attention caches are [L, B, KV/pack, S, pack*D]
+    (``layers.kv_cache_row``).
+    """
     dt = jnp.dtype(cfg.dtype)
+    max_seq = cache_capacity(max_seq)
     ln = cfg.n_layers
     sds = jax.ShapeDtypeStruct
     cache: Dict[str, Any] = {"pos": sds((), jnp.int32)}
@@ -130,19 +143,18 @@ def init_cache_specs(cfg: ModelConfig, batch: int, max_seq: int):
         d_in, h, n = ssm_mod.ssm_dims(cfg)
         cache["state"] = sds((ln, batch, h, cfg.ssm_head_dim, n), jnp.float32)
         cache["conv"] = sds((ln, batch, cfg.ssm_conv - 1, d_in + 2 * n), dt)
-        apps = n_shared_apps(cfg)
-        hd = cfg.head_dim_
-        cache["shared_k"] = sds((apps, batch, max_seq, cfg.n_kv_heads, hd), dt)
-        cache["shared_v"] = sds((apps, batch, max_seq, cfg.n_kv_heads, hd), dt)
+        kv = (n_shared_apps(cfg), batch) + kv_cache_row(cfg, max_seq)
+        cache["shared_k"] = sds(kv, dt)
+        cache["shared_v"] = sds(kv, dt)
         return cache
     if cfg.mla:
         cache["latent"] = sds((ln, batch, max_seq, cfg.kv_lora_rank), dt)
         cache["k_rope"] = sds((ln, batch, max_seq, cfg.rope_head_dim), dt)
         return cache
-    hd = cfg.head_dim_
-    cache["k"] = sds((ln, batch, max_seq, cfg.n_kv_heads, hd), dt)
-    cache["v"] = sds((ln, batch, max_seq, cfg.n_kv_heads, hd), dt)
+    cache["k"] = sds((ln, batch) + kv_cache_row(cfg, max_seq), dt)
+    cache["v"] = sds((ln, batch) + kv_cache_row(cfg, max_seq), dt)
     if cfg.family == "encdec":
+        hd = cfg.head_dim_
         cache["cross_k"] = sds((ln, batch, cfg.n_audio_frames,
                                 cfg.n_kv_heads, hd), dt)
         cache["cross_v"] = sds((ln, batch, cfg.n_audio_frames,
@@ -272,74 +284,67 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
     flags = layer_flags(cfg)
     shared = params.get("shared_attn")
 
+    # caches that grow by a position (k/v, the MLA latent, the shared k/v)
+    # ride in the scan's carry and take a one-position write in place; the
+    # SSM state, rewritten whole every step, is scanned as xs/ys
     if cfg.family in ("ssm", "hybrid"):
         scanned = {"params": params["layers"],
                    "state": cache["state"], "conv": cache["conv"]}
+        kv = {}
         if cfg.family == "hybrid":
             scanned.update(shared_apply=flags["shared_apply"],
                            shared_slot=flags["shared_slot"],
                            window=flags["window"])
+            kv = {"k": cache["shared_k"], "v": cache["shared_v"]}
 
         def body(carry, sc):
-            x, sk, sv = carry
+            x, kv = carry
             h = rms_norm(x, sc["params"]["ln1"], cfg.norm_eps)
             y, (st, cv) = ssm_mod.ssm_forward(
                 h, sc["params"]["ssm"], cfg, state=sc["state"],
                 conv_state=sc["conv"])
             x = x + y
             if cfg.family == "hybrid":
-                slot = sc["shared_slot"]
-
                 def with_attn(args):
-                    x, sk, sv = args
+                    x, kv = args
                     h2 = rms_norm(x, shared["ln"], cfg.norm_eps)
-                    kc = jax.lax.dynamic_index_in_dim(sk, slot, 0, False)
-                    vc = jax.lax.dynamic_index_in_dim(sv, slot, 0, False)
-                    a, nc = attention(h2, shared["attn"], cfg, positions,
-                                      kv_cache={"k": kc, "v": vc},
-                                      cache_pos=pos, window=sc["window"],
-                                      attn_fn=attn_fn)
-                    sk = jax.lax.dynamic_update_index_in_dim(sk, nc["k"], slot, 0)
-                    sv = jax.lax.dynamic_update_index_in_dim(sv, nc["v"], slot, 0)
+                    a, kv = attention(h2, shared["attn"], cfg, positions,
+                                      kv_cache=kv, cache_pos=pos,
+                                      cache_layer=sc["shared_slot"],
+                                      window=sc["window"], attn_fn=attn_fn)
                     x = x + a
                     h2 = rms_norm(x, shared["ln2"], cfg.norm_eps)
-                    return x + mlp(h2, shared["mlp"], cfg), sk, sv
+                    return x + mlp(h2, shared["mlp"], cfg), kv
 
-                x, sk, sv = jax.lax.cond(sc["shared_apply"], with_attn,
-                                         lambda a: a, (x, sk, sv))
-            return (x, sk, sv), (st, cv)
+                x, kv = jax.lax.cond(sc["shared_apply"], with_attn,
+                                     lambda a: a, (x, kv))
+            return (x, kv), (st, cv)
 
-        sk0 = cache.get("shared_k", jnp.zeros((0,), cfg.activation_dtype))
-        sv0 = cache.get("shared_v", jnp.zeros((0,), cfg.activation_dtype))
-        (x, sk, sv), (states, convs) = jax.lax.scan(body, (x, sk0, sv0),
-                                                    scanned)
+        (x, kv), (states, convs) = jax.lax.scan(body, (x, kv), scanned)
         new_cache = dict(cache, pos=pos + 1, state=states, conv=convs)
         if cfg.family == "hybrid":
-            new_cache.update(shared_k=sk, shared_v=sv)
+            new_cache.update(shared_k=kv["k"], shared_v=kv["v"])
     else:
-        scanned = {"params": params["layers"], "window": flags["window"]}
-        if cfg.mla:
-            scanned.update(latent=cache["latent"], k_rope=cache["k_rope"])
-        else:
-            scanned.update(k=cache["k"], v=cache["v"])
+        scanned = {"params": params["layers"], "window": flags["window"],
+                   "layer": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
+        names = ("latent", "k_rope") if cfg.mla else ("k", "v")
+        kv = {n: cache[n] for n in names}
         if cfg.family == "encdec":
             scanned.update(cross_k=cache["cross_k"], cross_v=cache["cross_v"])
 
-        def body(x, sc):
+        def body(carry, sc):
+            x, kv = carry
             lp = sc["params"]
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             if cfg.mla:
-                a, nc = mla_attention(h, lp["attn"], cfg, positions,
-                                      kv_cache={"latent": sc["latent"],
-                                                "k_rope": sc["k_rope"]},
-                                      cache_pos=pos)
-                out_caches = (nc["latent"], nc["k_rope"])
+                a, kv = mla_attention(h, lp["attn"], cfg, positions,
+                                      kv_cache=kv, cache_pos=pos,
+                                      cache_layer=sc["layer"])
             else:
-                a, nc = attention(h, lp["attn"], cfg, positions,
-                                  kv_cache={"k": sc["k"], "v": sc["v"]},
-                                  cache_pos=pos, window=sc["window"],
-                                  attn_fn=attn_fn)
-                out_caches = (nc["k"], nc["v"])
+                a, kv = attention(h, lp["attn"], cfg, positions,
+                                  kv_cache=kv, cache_pos=pos,
+                                  cache_layer=sc["layer"],
+                                  window=sc["window"], attn_fn=attn_fn)
             if cfg.post_norms:
                 a = rms_norm(a, lp["ln1_post"], cfg.norm_eps)
             x = x + a
@@ -359,14 +364,10 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
                 m = mlp(h, lp["mlp"], cfg)
             if cfg.post_norms:
                 m = rms_norm(m, lp["ln2_post"], cfg.norm_eps)
-            return x + m, out_caches
+            return (x + m, kv), None
 
-        x, out_caches = jax.lax.scan(body, x, scanned)
-        new_cache = dict(cache, pos=pos + 1)
-        if cfg.mla:
-            new_cache.update(latent=out_caches[0], k_rope=out_caches[1])
-        else:
-            new_cache.update(k=out_caches[0], v=out_caches[1])
+        (x, kv), _ = jax.lax.scan(body, (x, kv), scanned)
+        new_cache = dict(cache, pos=pos + 1, **kv)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head(x, params["embed"], cfg)

@@ -121,16 +121,22 @@ def input_shardings(input_sds: Dict[str, jax.ShapeDtypeStruct], mesh: Mesh,
     return out
 
 
+# the decode cache's attention entries (``models.transformer.init_cache_specs``)
+KV_CACHE_KEYS = ("k", "v", "shared_k", "shared_v")
+
+
 def cache_shardings(cache_sds, mesh: Mesh):
     """Decode-cache shardings.
 
-    Rule: shard batch over (pod, data); for the per-layer KV tensors
-    [L, B, S, KV, D] prefer kv-heads on "model" when divisible, else shard
-    the sequence dim on "model" (sequence-parallel attention over the cache).
+    Rule: shard batch over (pod, data); for the attention caches
+    [L, B, KV/pack, S, pack*D] (``models.layers.attention``) prefer the
+    kv-head rows on "model" when divisible, else shard the sequence dim on
+    "model" (sequence-parallel attention over the cache).  Other 5-D state
+    [L, B, H, P, N] prefers dim 3, then dim 2.
     """
     model_n = mesh.shape.get("model", 1)
 
-    def one(sds):
+    def one(path, sds):
         shape = sds.shape
         spec = [None] * len(shape)
         if len(shape) == 0:
@@ -139,17 +145,17 @@ def cache_shardings(cache_sds, mesh: Mesh):
         if len(shape) >= 2:
             bp = batch_pspec(mesh, shape[1], len(shape), 1)
             spec = list(bp)
-        if len(shape) == 5:          # [L/apps, B, S, KV, D]
-            if shape[3] % model_n == 0 and model_n > 1:
-                spec[3] = "model"
-            elif shape[2] % model_n == 0 and model_n > 1:
-                spec[2] = "model"
-        elif len(shape) == 4 and shape[-1] % model_n == 0 and model_n > 1:
-            spec[-1] = None          # ssm state [L,B,H,P,N]? handled below
+        if len(shape) == 5 and model_n > 1:
+            name = path[-1].key if path else None
+            order = (2, 3) if name in KV_CACHE_KEYS else (3, 2)
+            for dim in order:
+                if shape[dim] % model_n == 0:
+                    spec[dim] = "model"
+                    break
         if len(shape) == 4 and shape[2] % model_n == 0 and model_n > 1:
             # [L, B, S, latent] (MLA) or [L, B, H, ...]: shard dim 2
             spec[2] = "model"
         ns = NamedSharding(mesh, P(*spec))
         return jax.ShapeDtypeStruct(shape, sds.dtype, sharding=ns)
 
-    return jax.tree.map(one, cache_sds)
+    return jax.tree_util.tree_map_with_path(one, cache_sds)

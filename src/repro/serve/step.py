@@ -41,11 +41,17 @@ _SERVE_STEP_CACHE: Dict[Tuple[ModelConfig, Any], Any] = {}
 
 
 def jitted_serve_step(cfg: ModelConfig, attn_fn=None):
-    """The jitted decode step for ``cfg``, compiled once and reused."""
+    """The jitted decode step for ``cfg``, compiled once and reused.
+
+    The cache is donated: the step writes the new position into the
+    buffers it is given and returns them, so the cache passed in is
+    consumed and only the returned one may be used again.
+    """
     key = (cfg, attn_fn)
     step = _SERVE_STEP_CACHE.get(key)
     if step is None:
-        step = _SERVE_STEP_CACHE[key] = jax.jit(make_serve_step(cfg, attn_fn))
+        step = _SERVE_STEP_CACHE[key] = jax.jit(make_serve_step(cfg, attn_fn),
+                                                donate_argnums=(1,))
     return step
 
 

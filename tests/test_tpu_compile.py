@@ -8,6 +8,7 @@ loads the TPU library, which one process at a time may hold, so it must
 never happen while a module is imported or collected.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs as cfgs
 from repro.kernels import ops
 from repro.models import model as model_mod
-from repro.serve.step import make_serve_step
+from repro.serve.step import jitted_serve_step
 from repro.train import optimizer as opt_mod
 from repro.train.step import init_state, make_train_step
 
@@ -98,15 +99,39 @@ def test_qwen2_train_step_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES, mem
 
 
-def test_qwen2_decode_step_compiles(one_chip):
-    """The full-width decode step ``serve/step.greedy_generate`` jits."""
+def _decode_step(one_chip, batch, max_seq):
+    """The full-width qwen2-0.5b decode step ``serve/step.greedy_generate``
+    jits (cache donated), compiled at ``batch`` x ``max_seq``."""
     cfg = cfgs.get_config("qwen2-0.5b")
     on_chip = lambda a: _sds(one_chip, a.shape, a.dtype)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: model_mod.init_params(cfg, k), jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: model_mod.init_cache(cfg, 2, 32)))
-    mem = jax.jit(make_serve_step(cfg)).lower(
-        params, cache, _sds(one_chip, (2, 1), jnp.int32)) \
-        .compile().memory_analysis()
+        lambda: model_mod.init_cache(cfg, batch, max_seq)))
+    compiled = jitted_serve_step(cfg).lower(
+        params, cache, _sds(one_chip, (batch, 1), jnp.int32)).compile()
+    return compiled, cache
+
+
+def test_qwen2_decode_step_compiles(one_chip):
+    compiled, _ = _decode_step(one_chip, 2, 32)
+    mem = compiled.memory_analysis()
     assert 0 < mem.argument_size_in_bytes < HBM_BYTES, mem
+    # the cache is written in place: the output is the input's buffers but
+    # for the next tokens and the position
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+
+
+def test_qwen2_decode_step_reads_the_cache_in_place(one_chip):
+    """At the benchmark cell's shape (128 requests, 1149 positions) no
+    layer copies or re-lays the cache: the one-position writes are in
+    place and attention reads each layer's slice as it is stored."""
+    compiled, cache = _decode_step(one_chip, 128, 1149)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+    assert mem.temp_size_in_bytes < 2 ** 24, mem
+    capacity = cache["k"].shape[3]
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \S+\[[^\]]*\b%d\b[^\]]*\]\S* copy(-start)?\("
+                           % capacity, line)]
+    assert not copies, copies
