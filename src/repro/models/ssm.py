@@ -45,8 +45,9 @@ def _causal_conv(x, w, b, state: Optional[jnp.ndarray] = None):
     ([B,K-1,C]) performs the streaming update and returns (y, new_state)."""
     k = w.shape[0]
     if state is not None:
-        window = jnp.concatenate([state, x], axis=1)       # [B, K-1+S, C]
-        new_state = window[:, -(k - 1):]
+        with jax.named_scope("ssm_state"):
+            window = jnp.concatenate([state, x], axis=1)   # [B, K-1+S, C]
+            new_state = window[:, -(k - 1):]
     else:
         window = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
         new_state = None
@@ -123,10 +124,15 @@ def ssd_chunked_ref(x, dt, a, b_mat, c_mat, chunk: int,
     return y, last
 
 
+@jax.named_scope("ssm")
 def ssm_forward(x, p, cfg: ModelConfig, *, state=None, conv_state=None,
                 ssd_fn=None):
     """Full Mamba2 block.  ``state``/``conv_state`` given -> decode mode
-    (S small, typically 1); returns (y, (state, conv_state))."""
+    (S small, typically 1); returns (y, (state, conv_state)).
+
+    Named ``ssm``; in decode mode the ops that read or rewrite the state
+    (the recurrence with its read-out, the conv window's shift) are named
+    ``ssm_state`` inside it."""
     bsz, s, _ = x.shape
     d_in, h, n = ssm_dims(cfg)
     hd = cfg.ssm_head_dim
@@ -150,12 +156,13 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, conv_state=None,
         # O(1) decode recurrence (S == 1 expected)
         xs1 = xs[:, 0].astype(jnp.float32)                 # [B,H,P]
         dt1 = dt[:, 0]                                     # [B,H]
-        da = jnp.exp(dt1 * a[None, :])                     # [B,H]
-        upd = jnp.einsum("bh,bhp,bn->bhpn", dt1, xs1,
-                         b_mat[:, 0].astype(jnp.float32))
-        new_state = state * da[:, :, None, None] + upd
-        y = jnp.einsum("bhpn,bn->bhp", new_state,
-                       c_mat[:, 0].astype(jnp.float32))
+        with jax.named_scope("ssm_state"):
+            da = jnp.exp(dt1 * a[None, :])                 # [B,H]
+            upd = jnp.einsum("bh,bhp,bn->bhpn", dt1, xs1,
+                             b_mat[:, 0].astype(jnp.float32))
+            new_state = state * da[:, :, None, None] + upd
+            y = jnp.einsum("bhpn,bn->bhp", new_state,
+                           c_mat[:, 0].astype(jnp.float32))
         y = y + p["d_skip"][None, :, None] * xs1
         y = y.reshape(bsz, 1, d_in).astype(x.dtype)
         carry = (new_state, new_conv)

@@ -299,11 +299,16 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
 
         def body(carry, sc):
             x, kv = carry
-            h = rms_norm(x, sc["params"]["ln1"], cfg.norm_eps)
-            y, (st, cv) = ssm_mod.ssm_forward(
-                h, sc["params"]["ssm"], cfg, state=sc["state"],
-                conv_state=sc["conv"])
-            x = x + y
+            # the scan runs under ``ssm_state``, which names its slices and
+            # stacks of the per-layer state; what the body computes besides
+            # the state is named ``ssm`` here, the hybrid's shared block
+            # ``attn``
+            with jax.named_scope("ssm"):
+                h = rms_norm(x, sc["params"]["ln1"], cfg.norm_eps)
+                y, (st, cv) = ssm_mod.ssm_forward(
+                    h, sc["params"]["ssm"], cfg, state=sc["state"],
+                    conv_state=sc["conv"])
+                x = x + y
             if cfg.family == "hybrid":
                 def with_attn(args):
                     x, kv = args
@@ -316,11 +321,13 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
                     h2 = rms_norm(x, shared["ln2"], cfg.norm_eps)
                     return x + mlp(h2, shared["mlp"], cfg), kv
 
-                x, kv = jax.lax.cond(sc["shared_apply"], with_attn,
-                                     lambda a: a, (x, kv))
+                with jax.named_scope("attn"):
+                    x, kv = jax.lax.cond(sc["shared_apply"], with_attn,
+                                         lambda a: a, (x, kv))
             return (x, kv), (st, cv)
 
-        (x, kv), (states, convs) = jax.lax.scan(body, (x, kv), scanned)
+        with jax.named_scope("ssm_state"):
+            (x, kv), (states, convs) = jax.lax.scan(body, (x, kv), scanned)
         new_cache = dict(cache, pos=pos + 1, state=states, conv=convs)
         if cfg.family == "hybrid":
             new_cache.update(shared_k=kv["k"], shared_v=kv["v"])

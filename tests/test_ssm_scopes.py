@@ -1,0 +1,126 @@
+"""The decode step names the Mamba-2 mixer ``ssm`` and every op that reads,
+updates, shifts or stacks the recurrent or conv state ``ssm_state``, which
+is what the benchmark's ``ssm_state_share.decode`` reads out of a device
+trace; and the names change nothing that runs."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import configs as cfgs
+from repro.models import model as model_mod
+from repro.models import ssm as ssm_mod
+from repro.serve import step as step_mod
+
+# the names of the program's scopes
+SCOPES = ("embed", "attn", "kv_cache", "mlp", "lm_head", "ssm", "ssm_state")
+BATCH, MAX_SEQ = 2, 8
+
+
+def _innermost(stack: str) -> str:
+    return next((p for p in reversed(stack.split("/")) if p in SCOPES), "")
+
+
+def _ops(cfg):
+    """(kind, result dims, name stack) of each op of the compiled serve step
+    that carries a name stack."""
+    params = model_mod.init_params(cfg, jax.random.PRNGKey(0))
+    cache = model_mod.init_cache(cfg, BATCH, MAX_SEQ)
+    tokens = jnp.zeros((BATCH, 1), jnp.int32)
+    text = jax.jit(step_mod.make_serve_step(cfg)).lower(
+        params, cache, tokens).compile().as_text()
+    out = []
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(", line)
+        stack = re.search(r'op_name="([^"]*)"', line)
+        if op and stack:
+            dims = tuple(int(d) for d in op.group(1).split(",") if d)
+            out.append((op.group(2), dims, stack.group(1)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def mamba():
+    cfg = cfgs.get_smoke_config("mamba2-2.7b")
+    d_in, h, n = ssm_mod.ssm_dims(cfg)
+    state = (BATCH, h, cfg.ssm_head_dim, n)
+    conv = (BATCH, cfg.ssm_conv - 1, d_in + 2 * n)
+    return cfg, state, conv, _ops(cfg)
+
+
+def _scopes_of(ops, kind, dims):
+    return {_innermost(s) for k, d, s in ops if k == kind and d == dims}
+
+
+def test_mamba2_step_names_the_state_ops(mamba):
+    cfg, state, conv, ops = mamba
+    layers = cfg.n_layers
+    # the scan's writes of each layer's state into the stacked cache
+    assert _scopes_of(ops, "dynamic-update-slice", (layers,) + state) == \
+        {"ssm_state"}
+    assert _scopes_of(ops, "dynamic-update-slice", (layers,) + conv) == \
+        {"ssm_state"}
+    # the conv window: the new input joined to the state, then shifted
+    window = conv[:1] + (conv[1] + 1,) + conv[2:]
+    assert _scopes_of(ops, "concatenate", window) == {"ssm_state"}
+    assert _scopes_of(ops, "slice", conv) == {"ssm_state"}
+    # the recurrence, inside the mixer
+    rec = [s for k, d, s in ops if k in ("multiply", "add") and d == state]
+    assert rec and all(_innermost(s) == "ssm_state" and "/ssm/" in s
+                       for s in rec), rec
+    # no op with the state's shape, per layer or stacked, goes unnamed
+    shaped = {state, (1,) + state, (layers,) + state, conv, (1,) + conv,
+              (layers,) + conv}
+    stray = [(k, d, s) for k, d, s in ops if d in shaped
+             and k != "parameter" and _innermost(s) != "ssm_state"]
+    assert not stray, stray
+
+
+def test_mamba2_step_names_the_mixer(mamba):
+    cfg, _, _, ops = mamba
+    d_in, h, n = ssm_mod.ssm_dims(cfg)
+    # the input projection to z, x, B, C and dt
+    assert _scopes_of(ops, "dot", (BATCH, 2 * d_in + 2 * n + h)) == {"ssm"}
+    assert "lm_head" in {_innermost(s) for _, _, s in ops}
+
+
+def test_qwen2_step_carries_no_ssm_scope():
+    ops = _ops(cfgs.get_smoke_config("qwen2-0.5b"))
+    names = {p for _, _, s in ops for p in s.split("/")}
+    assert "attn" in names
+    assert not names & {"ssm", "ssm_state"}
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "qwen2-0.5b"])
+def test_scopes_change_no_arithmetic(arch, monkeypatch):
+    """The serve step lowers to the same module with every scope taken out,
+    and ``greedy_generate`` gives the same tokens bit for bit."""
+    cfg = cfgs.get_smoke_config(arch)
+    params = model_mod.init_params(cfg, jax.random.PRNGKey(1))
+    prompt = jax.random.randint(jax.random.PRNGKey(2), (BATCH, 3), 0,
+                                cfg.vocab, jnp.int32)
+    cache = model_mod.init_cache(cfg, BATCH, MAX_SEQ)
+
+    def lowered():
+        return jax.jit(step_mod.make_serve_step(cfg)).lower(
+            params, cache, prompt[:, :1]).as_text()
+
+    def generate():
+        return np.asarray(step_mod.greedy_generate(params, cfg, prompt, 4,
+                                                   MAX_SEQ))
+
+    named, tokens = lowered(), generate()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(ssm_mod, "ssm_forward",
+                        ssm_mod.ssm_forward.__wrapped__)
+    monkeypatch.setattr(step_mod, "_SERVE_STEP_CACHE", {})
+    plain = lowered()
+    assert "ssm_state" not in jax.jit(step_mod.make_serve_step(cfg)).lower(
+        params, cache, prompt[:, :1]).as_text(debug_info=True)
+    assert plain == named
+    np.testing.assert_array_equal(generate(), tokens)
