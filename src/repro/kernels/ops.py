@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
+from repro.kernels import ssm_decode as _ssm_dec
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -110,6 +111,20 @@ def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int = 256, h0=None, *,
                                    operating_point)
     return _ssd_jit(x, dt, a, b_mat, c_mat, h0, chunk=chunk,
                     interpret=_auto_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssm_decode_jit(state, layer, da, dtx, b_mat, c_mat, *, interpret: bool):
+    return _ssm_dec.ssm_decode_update(state, layer, da, dtx, b_mat, c_mat,
+                                      interpret=interpret)
+
+
+def ssm_decode_update(state, layer, da, dtx, b_mat, c_mat, *,
+                      interpret: Optional[bool] = None):
+    """One decode step of layer ``layer`` of the stacked SSM state, written
+    over the state's own buffer -> (state, y); see ``ssm_decode``."""
+    return _ssm_decode_jit(state, layer, da, dtx, b_mat, c_mat,
+                           interpret=_auto_interpret(interpret))
 
 
 def make_attn_fn(interpret: Optional[bool] = None, block_config=None):

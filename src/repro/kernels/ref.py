@@ -63,3 +63,14 @@ def ssd_chunk_ref(x, dt, a, b_mat, c_mat):
     state = jnp.einsum("bsn,bsh,bsh,bshp->bhpn",
                        b_mat, decay_end, dt, x)
     return y, state
+
+
+def ssm_decode_ref(state, da, dtx, b_mat, c_mat):
+    """One Mamba2 decode step of one layer: state [B,H,P,N], da [B,H],
+    dtx [B,H,P] (dt * x), b_mat/c_mat [B,N] -> (new state, y [B,H,P]).
+
+    new = state * da + dtx (x) B        y = new . C
+    """
+    new = state * da[:, :, None, None] + jnp.einsum("bhp,bn->bhpn", dtx,
+                                                    b_mat)
+    return new, jnp.einsum("bhpn,bn->bhp", new, c_mat)

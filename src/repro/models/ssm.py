@@ -2,8 +2,10 @@
 
 Training/prefill uses the chunked SSD algorithm (quadratic within a chunk,
 linear state recurrence across chunks); decode is the O(1) stateful
-recurrence.  The intra-chunk computation has a Pallas kernel
-(``repro.kernels.ssd_scan``) selected via ``cfg.attention_impl=='pallas'``.
+recurrence, whose state update is the Pallas kernel ``repro.kernels.
+ssm_decode`` (in place on the stacked state).  The intra-chunk computation
+has a Pallas kernel (``repro.kernels.ssd_scan``) selected via
+``cfg.attention_impl=='pallas'``.
 """
 from __future__ import annotations
 
@@ -125,10 +127,12 @@ def ssd_chunked_ref(x, dt, a, b_mat, c_mat, chunk: int,
 
 
 @jax.named_scope("ssm")
-def ssm_forward(x, p, cfg: ModelConfig, *, state=None, conv_state=None,
-                ssd_fn=None):
-    """Full Mamba2 block.  ``state``/``conv_state`` given -> decode mode
-    (S small, typically 1); returns (y, (state, conv_state)).
+def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
+                conv_state=None, ssd_fn=None):
+    """Full Mamba2 block.  ``state`` given -> decode mode (S small,
+    typically 1): ``state`` is the stacked per-layer state [L,B,H,P,N],
+    ``layer`` this block's index in it and ``conv_state`` this block's conv
+    window; returns (y, (state with the layer rewritten, conv_state)).
 
     Named ``ssm``; in decode mode the ops that read or rewrite the state
     (the recurrence with its read-out, the conv window's shift) are named
@@ -153,16 +157,20 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, conv_state=None,
     a = -jnp.exp(p["a_log"])
 
     if state is not None:
-        # O(1) decode recurrence (S == 1 expected)
+        # imported here: Pallas takes seconds to import, which only the SSM
+        # decode path should pay
+        from repro.kernels import ops as kernel_ops
+
+        # O(1) decode recurrence (S == 1 expected): one read and one write
+        # of the layer's state, in the stacked state's own buffer
         xs1 = xs[:, 0].astype(jnp.float32)                 # [B,H,P]
         dt1 = dt[:, 0]                                     # [B,H]
         with jax.named_scope("ssm_state"):
             da = jnp.exp(dt1 * a[None, :])                 # [B,H]
-            upd = jnp.einsum("bh,bhp,bn->bhpn", dt1, xs1,
-                             b_mat[:, 0].astype(jnp.float32))
-            new_state = state * da[:, :, None, None] + upd
-            y = jnp.einsum("bhpn,bn->bhp", new_state,
-                           c_mat[:, 0].astype(jnp.float32))
+            new_state, y = kernel_ops.ssm_decode_update(
+                state, layer, da, dt1[:, :, None] * xs1,
+                b_mat[:, 0].astype(jnp.float32),
+                c_mat[:, 0].astype(jnp.float32))
         y = y + p["d_skip"][None, :, None] * xs1
         y = y.reshape(bsz, 1, d_in).astype(x.dtype)
         carry = (new_state, new_conv)

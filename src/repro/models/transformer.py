@@ -284,12 +284,13 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
     flags = layer_flags(cfg)
     shared = params.get("shared_attn")
 
-    # caches that grow by a position (k/v, the MLA latent, the shared k/v)
-    # ride in the scan's carry and take a one-position write in place; the
-    # SSM state, rewritten whole every step, is scanned as xs/ys
+    # every cache rides in the scan's carry and is written in place at the
+    # scanned layer's index: the caches that grow by a position (k/v, the
+    # MLA latent, the shared k/v) take a one-position write, the SSM state
+    # and conv window a whole layer's
     if cfg.family in ("ssm", "hybrid"):
         scanned = {"params": params["layers"],
-                   "state": cache["state"], "conv": cache["conv"]}
+                   "layer": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
         kv = {}
         if cfg.family == "hybrid":
             scanned.update(shared_apply=flags["shared_apply"],
@@ -298,17 +299,20 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
             kv = {"k": cache["shared_k"], "v": cache["shared_v"]}
 
         def body(carry, sc):
-            x, kv = carry
-            # the scan runs under ``ssm_state``, which names its slices and
-            # stacks of the per-layer state; what the body computes besides
-            # the state is named ``ssm`` here, the hybrid's shared block
-            # ``attn``
+            x, state, conv, kv = carry
+            i = sc["layer"]
+            # the scan runs under ``ssm_state``, which names the conv
+            # window's read and write-back here; the mixer is named ``ssm``
+            # (its state update ``ssm_state`` inside it), the hybrid's
+            # shared block ``attn``
+            cv = jax.lax.dynamic_index_in_dim(conv, i, keepdims=False)
             with jax.named_scope("ssm"):
                 h = rms_norm(x, sc["params"]["ln1"], cfg.norm_eps)
-                y, (st, cv) = ssm_mod.ssm_forward(
-                    h, sc["params"]["ssm"], cfg, state=sc["state"],
-                    conv_state=sc["conv"])
+                y, (state, cv) = ssm_mod.ssm_forward(
+                    h, sc["params"]["ssm"], cfg, state=state, layer=i,
+                    conv_state=cv)
                 x = x + y
+            conv = jax.lax.dynamic_update_index_in_dim(conv, cv, i, 0)
             if cfg.family == "hybrid":
                 def with_attn(args):
                     x, kv = args
@@ -324,11 +328,12 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
                 with jax.named_scope("attn"):
                     x, kv = jax.lax.cond(sc["shared_apply"], with_attn,
                                          lambda a: a, (x, kv))
-            return (x, kv), (st, cv)
+            return (x, state, conv, kv), None
 
         with jax.named_scope("ssm_state"):
-            (x, kv), (states, convs) = jax.lax.scan(body, (x, kv), scanned)
-        new_cache = dict(cache, pos=pos + 1, state=states, conv=convs)
+            (x, state, conv, kv), _ = jax.lax.scan(
+                body, (x, cache["state"], cache["conv"], kv), scanned)
+        new_cache = dict(cache, pos=pos + 1, state=state, conv=conv)
         if cfg.family == "hybrid":
             new_cache.update(shared_k=kv["k"], shared_v=kv["v"])
     else:

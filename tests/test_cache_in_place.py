@@ -1,6 +1,7 @@
 """The decode cache is updated in place: the jitted serve step donates it
-and aliases every leaf, a launch writes the new position and nothing else,
-and donation leaves greedy decoding's tokens as they were."""
+and aliases every leaf, a launch writes the new position and nothing else
+(an SSM rewrites every layer's state and conv window), and donation leaves
+greedy decoding's tokens as they were."""
 import dataclasses
 
 import jax
@@ -16,10 +17,13 @@ from repro.serve.step import (greedy_generate, jitted_serve_step,
                               make_serve_step)
 
 B, P, NEW, MAX_SEQ = 2, 5, 4, 12
-ARCHS = ["qwen2-0.5b", "minicpm3-4b", "zamba2-2.7b"]   # dense, MLA, hybrid
+# dense, MLA, hybrid, SSM
+ARCHS = ["qwen2-0.5b", "minicpm3-4b", "zamba2-2.7b", "mamba2-2.7b"]
 # the sequence axis of each cache that grows by a position
 SEQ_AXIS = {"k": 3, "v": 3, "shared_k": 3, "shared_v": 3,
             "latent": 2, "k_rope": 2}
+# the caches every launch rewrites whole, a layer at a time
+REWRITTEN = ("state", "conv")
 
 
 def _setup(arch, dtype=None):
@@ -57,7 +61,13 @@ def test_a_launch_writes_its_position_and_nothing_else(arch):
                                              jnp.ones((B, 1), jnp.int32))
     assert int(after["pos"]) == 4
     grown = [name for name in after if name in SEQ_AXIS]
-    assert grown
+    rewritten = [name for name in after if name in REWRITTEN]
+    assert grown or rewritten
+    for name in rewritten:
+        # every layer advanced its own state and shifted its own window
+        new = np.asarray(after[name])
+        assert np.all(np.any(new != before[name], axis=tuple(
+            range(1, new.ndim)))), name
     for name in grown:
         old = np.moveaxis(before[name], SEQ_AXIS[name], 0)
         new = np.moveaxis(np.asarray(after[name]), SEQ_AXIS[name], 0)
@@ -68,8 +78,8 @@ def test_a_launch_writes_its_position_and_nothing_else(arch):
             range(1, new[3].ndim)))), name
 
 
-def test_greedy_generate_is_unchanged_by_donation():
-    cfg, params = _setup("qwen2-0.5b")
+def _greedy_matches_undonated_launches(arch):
+    cfg, params = _setup(arch)
     prompt = jax.random.randint(jax.random.PRNGKey(2), (B, P), 0, cfg.vocab,
                                 jnp.int32)
     out = greedy_generate(params, cfg, prompt, max_new=NEW, max_seq=MAX_SEQ)
@@ -90,7 +100,19 @@ def test_greedy_generate_is_unchanged_by_donation():
     # the donated step consumes the cache it is given
     cache = M.init_cache(cfg, B, MAX_SEQ)
     _, kept = jitted_serve_step(cfg)(params, cache, tok)
-    assert cache["k"].is_deleted() and not kept["k"].is_deleted()
+    leaf = "k" if "k" in cache else "state"
+    assert cache[leaf].is_deleted() and not kept[leaf].is_deleted()
+
+
+def test_greedy_generate_is_unchanged_by_donation():
+    _greedy_matches_undonated_launches("qwen2-0.5b")
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "zamba2-2.7b"])
+def test_greedy_generate_is_unchanged_by_donating_the_state(arch):
+    """The SSM state, rewritten in place by the decode kernel, gives the
+    tokens an undonated per-launch loop gives."""
+    _greedy_matches_undonated_launches(arch)
 
 
 def test_attention_caches_hold_whole_lane_rows_and_tiles():

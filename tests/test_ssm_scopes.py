@@ -1,7 +1,7 @@
 """The decode step names the Mamba-2 mixer ``ssm`` and every op that reads,
-updates, shifts or stacks the recurrent or conv state ``ssm_state``, which
-is what the benchmark's ``ssm_state_share.decode`` reads out of a device
-trace; and the names change nothing that runs."""
+updates, shifts or writes back the recurrent or conv state ``ssm_state``,
+which is what the benchmark's ``ssm_state_share.decode`` reads out of a
+device trace; and the names change nothing that runs."""
 import contextlib
 import re
 
@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from jax.extend import core as jex_core
 
 from repro import configs as cfgs
 from repro.models import model as model_mod
@@ -43,13 +45,46 @@ def _ops(cfg):
     return out
 
 
+def _subjaxprs(params):
+    for value in params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            if isinstance(v, jex_core.ClosedJaxpr):
+                yield v.jaxpr
+            elif isinstance(v, jex_core.Jaxpr):
+                yield v
+
+
+def _kernel_stacks(cfg):
+    """The name stack of each Pallas call of the serve step, with the
+    stacks of the loops and calls around it: on the chip each call is one
+    custom call, and its op carries this stack."""
+    params = model_mod.init_params(cfg, jax.random.PRNGKey(0))
+    cache = model_mod.init_cache(cfg, BATCH, MAX_SEQ)
+    closed = jax.make_jaxpr(step_mod.make_serve_step(cfg))(
+        params, cache, jnp.zeros((BATCH, 1), jnp.int32))
+    stacks = []
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            own = str(eqn.source_info.name_stack)
+            stack = "/".join(p for p in (outer, own) if p)
+            if eqn.primitive.name == "pallas_call":
+                stacks.append(stack)
+                continue
+            for sub in _subjaxprs(eqn.params):
+                walk(sub, stack)
+
+    walk(closed.jaxpr, "")
+    return stacks
+
+
 @pytest.fixture(scope="module")
 def mamba():
     cfg = cfgs.get_smoke_config("mamba2-2.7b")
     d_in, h, n = ssm_mod.ssm_dims(cfg)
     state = (BATCH, h, cfg.ssm_head_dim, n)
     conv = (BATCH, cfg.ssm_conv - 1, d_in + 2 * n)
-    return cfg, state, conv, _ops(cfg)
+    return cfg, state, conv, _ops(cfg), _kernel_stacks(cfg)
 
 
 def _scopes_of(ops, kind, dims):
@@ -57,11 +92,14 @@ def _scopes_of(ops, kind, dims):
 
 
 def test_mamba2_step_names_the_state_ops(mamba):
-    cfg, state, conv, ops = mamba
+    cfg, state, conv, ops, kernels = mamba
     layers = cfg.n_layers
-    # the scan's writes of each layer's state into the stacked cache
-    assert _scopes_of(ops, "dynamic-update-slice", (layers,) + state) == \
-        {"ssm_state"}
+    # the state's one read and write, in place in the stacked cache: the
+    # kernel's call inside the mixer (a custom call on the chip)
+    assert len(kernels) == 1, kernels
+    assert _innermost(kernels[0]) == "ssm_state" and "/ssm/" in kernels[0], \
+        kernels
+    # the write-back of each layer's conv window into the stacked cache
     assert _scopes_of(ops, "dynamic-update-slice", (layers,) + conv) == \
         {"ssm_state"}
     # the conv window: the new input joined to the state, then shifted
@@ -81,7 +119,7 @@ def test_mamba2_step_names_the_state_ops(mamba):
 
 
 def test_mamba2_step_names_the_mixer(mamba):
-    cfg, _, _, ops = mamba
+    cfg, _, _, ops, _ = mamba
     d_in, h, n = ssm_mod.ssm_dims(cfg)
     # the input projection to z, x, B, C and dt
     assert _scopes_of(ops, "dot", (BATCH, 2 * d_in + 2 * n + h)) == {"ssm"}

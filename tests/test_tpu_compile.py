@@ -7,6 +7,7 @@ The topology is described inside a module fixture only: describing it
 loads the TPU library, which one process at a time may hold, so it must
 never happen while a module is imported or collected.
 """
+import dataclasses
 import os
 import re
 
@@ -18,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs as cfgs
 from repro.kernels import ops
 from repro.models import model as model_mod
+from repro.serve import step as step_mod
 from repro.serve.step import jitted_serve_step
 from repro.train import optimizer as opt_mod
 from repro.train.step import init_state, make_train_step
@@ -80,6 +82,17 @@ def test_ssd_compiles_at_mamba2_widths(one_chip):
              _sds(one_chip, (1, s, n)))
 
 
+def test_ssm_decode_compiles_at_mamba2_widths(one_chip):
+    layers, b, h, p, n = 8, 128, 80, 64, 128
+    f32 = jnp.float32
+    _compile(lambda st, i, da, dtx, bm, cm: ops.ssm_decode_update(
+        st, i, da, dtx, bm, cm, interpret=False),
+        _sds(one_chip, (layers, b, h, p, n), f32),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (b, h), f32),
+        _sds(one_chip, (b, h, p), f32), _sds(one_chip, (b, n), f32),
+        _sds(one_chip, (b, n), f32))
+
+
 def test_qwen2_train_step_fits_one_chip(one_chip):
     """The full-width qwen2-0.5b step that ``launch/train.run`` jits, at
     4 x 1024: compiled, and its arguments, outputs and temporaries fit."""
@@ -99,10 +112,11 @@ def test_qwen2_train_step_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES, mem
 
 
-def _decode_step(one_chip, batch, max_seq):
-    """The full-width qwen2-0.5b decode step ``serve/step.greedy_generate``
-    jits (cache donated), compiled at ``batch`` x ``max_seq``."""
-    cfg = cfgs.get_config("qwen2-0.5b")
+def _decode_step(one_chip, batch, max_seq, cfg=None):
+    """The full-width decode step ``serve/step.greedy_generate`` jits (cache
+    donated) for ``cfg`` (qwen2-0.5b unless given), compiled at ``batch`` x
+    ``max_seq``."""
+    cfg = cfg or cfgs.get_config("qwen2-0.5b")
     on_chip = lambda a: _sds(one_chip, a.shape, a.dtype)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: model_mod.init_params(cfg, k), jax.random.PRNGKey(0)))
@@ -134,4 +148,51 @@ def test_qwen2_decode_step_reads_the_cache_in_place(one_chip):
     copies = [line for line in compiled.as_text().splitlines()
               if re.search(r"= \S+\[[^\]]*\b%d\b[^\]]*\]\S* copy(-start)?\("
                            % capacity, line)]
+    assert not copies, copies
+
+
+def test_mamba2_decode_step_updates_the_state_in_place(one_chip,
+                                                       monkeypatch):
+    """At the benchmark cell's shape (mamba2-2.7b cut to 8 layers, 128
+    requests, 1149 positions) the decode kernel reads and writes each
+    layer's state once, in the donated cache's own buffer: no copy of the
+    stacked state, and no staging buffer for a layer of it."""
+    # off a TPU the program takes the kernel's interpreter; this compiles
+    # for one, so the compiled kernel is steered in here
+    monkeypatch.setattr(ops, "_auto_interpret", lambda interpret: False)
+    monkeypatch.setattr(step_mod, "_SERVE_STEP_CACHE", {})
+    cfg = dataclasses.replace(cfgs.get_config("mamba2-2.7b"), n_layers=8)
+    compiled, cache = _decode_step(one_chip, 128, 1149, cfg)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+    assert mem.temp_size_in_bytes < 2 ** 24, mem
+    assert cache["state"].shape == (8, 128, 80, 64, 128)
+    shape = r"f32\[8,128,80,64,128\]"
+    lines = compiled.as_text().splitlines()
+    copies = [line for line in lines
+              if re.search(r"= %s\S* copy(-start)?\(" % shape, line)]
+    assert not copies, copies
+    # the state's one read and write is the kernel's call, named ssm_state
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in lines
+             if re.search(shape, line) and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert [p for p in calls[0].split("/")
+            if p in ("ssm", "ssm_state")][-1] == "ssm_state", calls
+
+
+def test_zamba2_decode_step_updates_the_state_in_place(one_chip,
+                                                       monkeypatch):
+    """zamba2-2.7b's state (N = 64) is not laid out row-major on the TPU,
+    so its layers are updated by XLA in the carry: still no copy of the
+    stacked state, and no relayout of it on the step's entry or exit."""
+    monkeypatch.setattr(ops, "_auto_interpret", lambda interpret: False)
+    monkeypatch.setattr(step_mod, "_SERVE_STEP_CACHE", {})
+    compiled, cache = _decode_step(one_chip, 16, 1149,
+                                   cfgs.get_config("zamba2-2.7b"))
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+    assert mem.temp_size_in_bytes < 2 ** 24, mem
+    shape = r"f32\[%s\]" % ",".join(map(str, cache["state"].shape))
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= %s\S* copy(-start)?\(" % shape, line)]
     assert not copies, copies
