@@ -77,7 +77,6 @@ class ModelConfig:
     remat: bool = True
     remat_policy: str = "full"   # full | save_collectives (§Perf A6/B4)
     seq_parallel: bool = False   # residual sharded on (model, seq) — §Perf
-    attention_impl: str = "xla"                 # xla | pallas
     optimizer_dtype: str = "float32"            # adam m/v dtype
 
     # ---------------------------------------------------------------

@@ -128,8 +128,8 @@ def ssm_decode_update(state, layer, da, dtx, b_mat, c_mat, *,
 
 
 def make_attn_fn(interpret: Optional[bool] = None, block_config=None):
-    """Adapter for ``ModelConfig.attention_impl == 'pallas'``: the model
-    layer calls attn_fn(q, k, v, cfg) on the full-sequence path."""
+    """Flash attention as the model's ``attn_fn`` hook: the model layer
+    calls attn_fn(q, k, v, cfg) on the full-sequence path."""
     def attn_fn(q, k, v, cfg):
         h, kvh = q.shape[2], k.shape[2]
         if kvh != h:

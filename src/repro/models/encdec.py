@@ -9,8 +9,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
-from repro.models.layers import (attention, attention_specs, embed, lm_head,
-                                 mlp, mlp_specs, rms_norm)
+from repro.models.layers import (attention_specs, embed, lm_head, mlp_specs,
+                                 rms_norm)
 
 
 def encoder_layer_specs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -38,16 +38,19 @@ def encode(params, encoder_embeds, cfg: ModelConfig):
                                  (bsz, frames))
 
     def body(x, lp):
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, _ = attention(h, lp["attn"], cfg, positions, causal=False)
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        return x + mlp(h, lp["mlp"], cfg), ()
+        x, _, _ = tfm._attn_layer(x, lp, cfg, positions, causal=False)
+        return x, ()
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, params["encoder"])
     return rms_norm(x, params["enc_final_norm"], cfg.norm_eps)
+
+
+def _cross_kv(enc, p):
+    """One decoder layer's cross-attention (k, v) of the encoder output."""
+    return (jnp.einsum("bsd,dhk->bshk", enc, p["wk"]),
+            jnp.einsum("bsd,dhk->bshk", enc, p["wv"]))
 
 
 def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
@@ -61,17 +64,9 @@ def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
                                  (bsz, seq))
 
     def body(x, lp):
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, _ = attention(h, lp["attn"], cfg, positions, attn_fn=attn_fn)
-        x = x + a
-        h = rms_norm(x, lp["ln_cross"], cfg.norm_eps)
-        ck = jnp.einsum("bsd,dhk->bshk", enc, lp["cross"]["wk"])
-        cv = jnp.einsum("bsd,dhk->bshk", enc, lp["cross"]["wv"])
-        a, _ = attention(h, lp["cross"], cfg, positions,
-                         kv_override=(ck, cv))
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        return x + mlp(h, lp["mlp"], cfg), ()
+        x, _, _ = tfm._attn_layer(x, lp, cfg, positions, attn_fn=attn_fn,
+                                  cross=_cross_kv(enc, lp["cross"]))
+        return x, ()
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -85,9 +80,7 @@ def prefill_cross_cache(params, encoder_embeds, cfg: ModelConfig):
     enc = encode(params, encoder_embeds, cfg)
 
     def body(_, lp):
-        ck = jnp.einsum("bsd,dhk->bshk", enc, lp["cross"]["wk"])
-        cv = jnp.einsum("bsd,dhk->bshk", enc, lp["cross"]["wv"])
-        return None, (ck, cv)
+        return None, _cross_kv(enc, lp["cross"])
 
     _, (cks, cvs) = jax.lax.scan(body, None, params["layers"])
     return cks, cvs

@@ -32,8 +32,9 @@ def forward(params, batch, cfg: ModelConfig, attn_fn=None):
 
 
 def decode_step(params, cache, tokens, cfg: ModelConfig, attn_fn=None):
-    return transformer.decode_step(params, cache, tokens, cfg,
-                                   attn_fn=attn_fn)
+    """``attn_fn`` is taken for the serve step's signature and unused:
+    attention against a cache has no fused kernel."""
+    return transformer.decode_step(params, cache, tokens, cfg)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int):

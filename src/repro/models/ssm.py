@@ -3,9 +3,7 @@
 Training/prefill uses the chunked SSD algorithm (quadratic within a chunk,
 linear state recurrence across chunks); decode is the O(1) stateful
 recurrence, whose state update is the Pallas kernel ``repro.kernels.
-ssm_decode`` (in place on the stacked state).  The intra-chunk computation
-has a Pallas kernel (``repro.kernels.ssd_scan``) selected via
-``cfg.attention_impl=='pallas'``.
+ssm_decode`` (in place on the stacked state).
 """
 from __future__ import annotations
 
@@ -128,7 +126,7 @@ def ssd_chunked_ref(x, dt, a, b_mat, c_mat, chunk: int,
 
 @jax.named_scope("ssm")
 def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
-                conv_state=None, ssd_fn=None):
+                conv_state=None):
     """Full Mamba2 block.  ``state`` given -> decode mode (S small,
     typically 1): ``state`` is the stacked per-layer state [L,B,H,P,N],
     ``layer`` this block's index in it and ``conv_state`` this block's conv
@@ -175,8 +173,7 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
         y = y.reshape(bsz, 1, d_in).astype(x.dtype)
         carry = (new_state, new_conv)
     else:
-        fn = ssd_fn or ssd_chunked_ref
-        y4, last = fn(xs, dt, a, b_mat, c_mat, cfg.ssm_chunk)
+        y4, last = ssd_chunked_ref(xs, dt, a, b_mat, c_mat, cfg.ssm_chunk)
         y4 = y4 + p["d_skip"][None, None, :, None] * xs.astype(y4.dtype)
         y = y4.reshape(bsz, s, d_in).astype(x.dtype)
         carry = (last, new_conv)

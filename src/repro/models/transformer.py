@@ -44,11 +44,8 @@ def _norm_spec(cfg: ModelConfig) -> PSpec:
 # Param specs.
 # ---------------------------------------------------------------------------
 def decoder_layer_specs(cfg: ModelConfig) -> Dict[str, Any]:
-    if cfg.family == "ssm" or (cfg.family == "hybrid"):
-        out = {"ln1": _norm_spec(cfg), "ssm": ssm_mod.ssm_specs(cfg)}
-        if cfg.family == "hybrid":
-            return out
-        return out
+    if cfg.family in ("ssm", "hybrid"):
+        return {"ln1": _norm_spec(cfg), "ssm": ssm_mod.ssm_specs(cfg)}
     out: Dict[str, Any] = {"ln1": _norm_spec(cfg), "ln2": _norm_spec(cfg)}
     if cfg.mla:
         out["attn"] = mla_specs(cfg)
@@ -134,18 +131,14 @@ def init_cache_specs(cfg: ModelConfig, batch: int, max_seq: int):
     ln = cfg.n_layers
     sds = jax.ShapeDtypeStruct
     cache: Dict[str, Any] = {"pos": sds((), jnp.int32)}
-    if cfg.family == "ssm":
+    if cfg.family in ("ssm", "hybrid"):
         d_in, h, n = ssm_mod.ssm_dims(cfg)
         cache["state"] = sds((ln, batch, h, cfg.ssm_head_dim, n), jnp.float32)
         cache["conv"] = sds((ln, batch, cfg.ssm_conv - 1, d_in + 2 * n), dt)
-        return cache
-    if cfg.family == "hybrid":
-        d_in, h, n = ssm_mod.ssm_dims(cfg)
-        cache["state"] = sds((ln, batch, h, cfg.ssm_head_dim, n), jnp.float32)
-        cache["conv"] = sds((ln, batch, cfg.ssm_conv - 1, d_in + 2 * n), dt)
-        kv = (n_shared_apps(cfg), batch) + kv_cache_row(cfg, max_seq)
-        cache["shared_k"] = sds(kv, dt)
-        cache["shared_v"] = sds(kv, dt)
+        if cfg.family == "hybrid":
+            kv = (n_shared_apps(cfg), batch) + kv_cache_row(cfg, max_seq)
+            cache["shared_k"] = sds(kv, dt)
+            cache["shared_v"] = sds(kv, dt)
         return cache
     if cfg.mla:
         cache["latent"] = sds((ln, batch, max_seq, cfg.kv_lora_rank), dt)
@@ -168,15 +161,31 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
 
 
 # ---------------------------------------------------------------------------
-# Forward (train / prefill).
+# Decoder blocks: one function per kind, scanned by ``forward``,
+# ``decode_step`` and ``encdec.forward`` (the attention block by
+# ``encdec.encode`` too).  Given a cache slot, a block writes it and
+# returns it.
 # ---------------------------------------------------------------------------
-def _dense_layer(x, lp, cfg, positions, window, mrope_sections, attn_fn):
+def _attn_layer(x, lp, cfg, positions, *, window=None, mrope_sections=None,
+                attn_fn=None, kv=None, cache_pos=None, layer=None,
+                cross=None, causal=True):
+    """Self attention (MLA where ``cfg.mla``), then cross attention over
+    ``cross=(k, v)`` where given, then the dense or MoE MLP, each behind
+    its norm and residual.
+
+    ``kv``: the stacked caches of every layer, written at ``cache_pos`` in
+    layer ``layer``; ``attn_fn`` takes over attention without them.
+    Returns (x, MoE aux or None, kv).
+    """
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.mla:
-        a, _ = mla_attention(h, lp["attn"], cfg, positions)
+        a, kv = mla_attention(h, lp["attn"], cfg, positions, kv_cache=kv,
+                              cache_pos=cache_pos, cache_layer=layer)
     else:
-        a, _ = attention(h, lp["attn"], cfg, positions, window=window,
-                         mrope_sections=mrope_sections, attn_fn=attn_fn)
+        a, kv = attention(h, lp["attn"], cfg, positions, kv_cache=kv,
+                          cache_pos=cache_pos, cache_layer=layer,
+                          window=window, mrope_sections=mrope_sections,
+                          attn_fn=attn_fn, causal=causal)
     # name the post-collective activations so the save_collectives remat
     # policy keeps them: the backward then never re-runs the TP all-reduces
     # / MoE all-to-alls of the forward (§Perf A6/B4)
@@ -184,6 +193,10 @@ def _dense_layer(x, lp, cfg, positions, window, mrope_sections, attn_fn):
     if cfg.post_norms:
         a = rms_norm(a, lp["ln1_post"], cfg.norm_eps)
     x = x + a
+    if cross is not None:
+        h = rms_norm(x, lp["ln_cross"], cfg.norm_eps)
+        a, _ = attention(h, lp["cross"], cfg, positions, kv_override=cross)
+        x = x + a
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.n_experts:
         b, s, d = h.shape
@@ -196,9 +209,44 @@ def _dense_layer(x, lp, cfg, positions, window, mrope_sections, attn_fn):
     m = checkpoint_name(m, "mlp_out")
     if cfg.post_norms:
         m = rms_norm(m, lp["ln2_post"], cfg.norm_eps)
-    return x + m, aux
+    return x + m, aux, kv
 
 
+def _ssm_layer(x, lp, cfg, *, state=None, layer=None, conv=None):
+    """The Mamba-2 mixer behind its norm and residual, named ``ssm``.
+
+    ``state``: the stacked state [L,B,H,P,N], rewritten at layer ``layer``;
+    ``conv``: this layer's conv window.  Returns (x, (state, conv)).
+    """
+    with jax.named_scope("ssm"):
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        y, carry = ssm_mod.ssm_forward(h, lp["ssm"], cfg, state=state,
+                                       layer=layer, conv_state=conv)
+        return x + y, carry
+
+
+def _shared_block(x, sp, cfg, positions, apply, window, *, kv=None,
+                  cache_pos=None, slot=None):
+    """The hybrid's shared attention and MLP block where ``apply`` holds,
+    named ``attn``.  ``kv``: the shared k/v caches, written at ``cache_pos``
+    in slot ``slot``.  Returns (x, kv)."""
+    def with_attn(args):
+        x, kv = args
+        h = rms_norm(x, sp["ln"], cfg.norm_eps)
+        a, kv = attention(h, sp["attn"], cfg, positions, kv_cache=kv,
+                          cache_pos=cache_pos, cache_layer=slot,
+                          window=window)
+        x = x + a
+        h = rms_norm(x, sp["ln2"], cfg.norm_eps)
+        return x + mlp(h, sp["mlp"], cfg), kv
+
+    with jax.named_scope("attn"):
+        return jax.lax.cond(apply, with_attn, lambda a: a, (x, kv))
+
+
+# ---------------------------------------------------------------------------
+# Forward (train / prefill).
+# ---------------------------------------------------------------------------
 def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
             attn_fn=None):
     """Full-sequence forward -> logits [B,S,V] (train & prefill path)."""
@@ -216,35 +264,28 @@ def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
         mrope_sections = None
     flags = layer_flags(cfg)
     shared = params.get("shared_attn")
-    aux_sum = jnp.zeros((), jnp.float32)
 
     def body(x, scanned):
         lp = scanned["params"]
         # sequence parallelism: the residual lives seq-sharded on the model
         # axis between layers; TP matmuls gather/reduce-scatter around it
         x = constrain(x, [BATCH, MODEL if cfg.seq_parallel else None, None])
-        aux_local = jnp.zeros((), jnp.float32)
+        aux = None
         if cfg.family in ("ssm", "hybrid"):
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            y, _ = ssm_mod.ssm_forward(h, lp["ssm"], cfg)
-            x = x + y
+            x, _ = _ssm_layer(x, lp, cfg)
             if cfg.family == "hybrid":
-                def with_attn(x):
-                    h2 = rms_norm(x, shared["ln"], cfg.norm_eps)
-                    a, _ = attention(h2, shared["attn"], cfg, positions,
-                                     window=scanned["window"])
-                    x = x + a
-                    h2 = rms_norm(x, shared["ln2"], cfg.norm_eps)
-                    return x + mlp(h2, shared["mlp"], cfg)
-                x = jax.lax.cond(scanned["shared_apply"], with_attn,
-                                 lambda x: x, x)
+                x, _ = _shared_block(x, shared, cfg, positions,
+                                     scanned["shared_apply"],
+                                     scanned["window"])
         else:
-            x, aux = _dense_layer(x, lp, cfg, positions, scanned["window"],
-                                  mrope_sections, attn_fn)
-            if aux is not None:
-                aux_local = (aux["load_balance"]
-                             + 1e-3 * aux["router_z"]).astype(jnp.float32)
-        return x, aux_local
+            x, aux, _ = _attn_layer(x, lp, cfg, positions,
+                                    window=scanned["window"],
+                                    mrope_sections=mrope_sections,
+                                    attn_fn=attn_fn)
+        if aux is None:
+            return x, jnp.zeros((), jnp.float32)
+        return x, (aux["load_balance"]
+                   + 1e-3 * aux["router_z"]).astype(jnp.float32)
 
     if cfg.remat:
         policy = None
@@ -265,22 +306,12 @@ def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
 # ---------------------------------------------------------------------------
 # Decode (one new token against a cache).
 # ---------------------------------------------------------------------------
-def decode_step(params, cache, tokens, cfg: ModelConfig,
-                positions_override=None, attn_fn=None):
-    """tokens [B, 1] -> (logits [B,1,V], new cache).
-
-    ``attn_fn`` reaches the attention layer with the same contract as the
-    forward path: a fused kernel that takes over when attention runs
-    without a KV cache.  The cached decode path keeps the reference
-    attention (today's flash hook is full-sequence only), so threading the
-    hook here is signature parity with ``forward`` — callers configure one
-    kernel once for both paths.
-    """
+def decode_step(params, cache, tokens, cfg: ModelConfig):
+    """tokens [B, 1] -> (logits [B,1,V], new cache)."""
     bsz = tokens.shape[0]
     pos = cache["pos"]
     x = embed(tokens, params["embed"], cfg)
-    positions = (positions_override if positions_override is not None
-                 else jnp.full((bsz, 1), pos, jnp.int32))
+    positions = jnp.full((bsz, 1), pos, jnp.int32)
     flags = layer_flags(cfg)
     shared = params.get("shared_attn")
 
@@ -302,32 +333,16 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
             x, state, conv, kv = carry
             i = sc["layer"]
             # the scan runs under ``ssm_state``, which names the conv
-            # window's read and write-back here; the mixer is named ``ssm``
-            # (its state update ``ssm_state`` inside it), the hybrid's
-            # shared block ``attn``
+            # window's read and write-back here
             cv = jax.lax.dynamic_index_in_dim(conv, i, keepdims=False)
-            with jax.named_scope("ssm"):
-                h = rms_norm(x, sc["params"]["ln1"], cfg.norm_eps)
-                y, (state, cv) = ssm_mod.ssm_forward(
-                    h, sc["params"]["ssm"], cfg, state=state, layer=i,
-                    conv_state=cv)
-                x = x + y
+            x, (state, cv) = _ssm_layer(x, sc["params"], cfg, state=state,
+                                        layer=i, conv=cv)
             conv = jax.lax.dynamic_update_index_in_dim(conv, cv, i, 0)
             if cfg.family == "hybrid":
-                def with_attn(args):
-                    x, kv = args
-                    h2 = rms_norm(x, shared["ln"], cfg.norm_eps)
-                    a, kv = attention(h2, shared["attn"], cfg, positions,
-                                      kv_cache=kv, cache_pos=pos,
-                                      cache_layer=sc["shared_slot"],
-                                      window=sc["window"], attn_fn=attn_fn)
-                    x = x + a
-                    h2 = rms_norm(x, shared["ln2"], cfg.norm_eps)
-                    return x + mlp(h2, shared["mlp"], cfg), kv
-
-                with jax.named_scope("attn"):
-                    x, kv = jax.lax.cond(sc["shared_apply"], with_attn,
-                                         lambda a: a, (x, kv))
+                x, kv = _shared_block(x, shared, cfg, positions,
+                                      sc["shared_apply"], sc["window"],
+                                      kv=kv, cache_pos=pos,
+                                      slot=sc["shared_slot"])
             return (x, state, conv, kv), None
 
         with jax.named_scope("ssm_state"):
@@ -346,37 +361,12 @@ def decode_step(params, cache, tokens, cfg: ModelConfig,
 
         def body(carry, sc):
             x, kv = carry
-            lp = sc["params"]
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            if cfg.mla:
-                a, kv = mla_attention(h, lp["attn"], cfg, positions,
-                                      kv_cache=kv, cache_pos=pos,
-                                      cache_layer=sc["layer"])
-            else:
-                a, kv = attention(h, lp["attn"], cfg, positions,
-                                  kv_cache=kv, cache_pos=pos,
-                                  cache_layer=sc["layer"],
-                                  window=sc["window"], attn_fn=attn_fn)
-            if cfg.post_norms:
-                a = rms_norm(a, lp["ln1_post"], cfg.norm_eps)
-            x = x + a
-            if cfg.family == "encdec":
-                h = rms_norm(x, lp["ln_cross"], cfg.norm_eps)
-                a, _ = attention(h, lp["cross"], cfg, positions,
-                                 kv_override=(sc["cross_k"], sc["cross_v"]))
-                x = x + a
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.n_experts:
-                b2, s2, d2 = h.shape
-                m, _ = moe_mod.moe_mlp(h.reshape(b2 * s2, d2), lp["moe"], cfg)
-                m = m.reshape(b2, s2, d2)
-                if cfg.moe_dense_residual:
-                    m = m + mlp(h, lp["mlp"], cfg)
-            else:
-                m = mlp(h, lp["mlp"], cfg)
-            if cfg.post_norms:
-                m = rms_norm(m, lp["ln2_post"], cfg.norm_eps)
-            return (x + m, kv), None
+            cross = ((sc["cross_k"], sc["cross_v"]) if cfg.family == "encdec"
+                     else None)
+            x, _, kv = _attn_layer(x, sc["params"], cfg, positions,
+                                   window=sc["window"], kv=kv, cache_pos=pos,
+                                   layer=sc["layer"], cross=cross)
+            return (x, kv), None
 
         (x, kv), _ = jax.lax.scan(body, (x, kv), scanned)
         new_cache = dict(cache, pos=pos + 1, **kv)

@@ -15,9 +15,9 @@ pytestmark = pytest.mark.slow   # heavy model/distributed tier
 
 B, S = 2, 8
 
-# f32 smoke variants for tight comparison
-ARCHS = ["qwen2-0.5b", "gemma2-27b", "h2o-danube-3-4b", "minicpm3-4b",
-         "mamba2-2.7b", "zamba2-2.7b", "arctic-480b", "qwen2-vl-7b"]
+# f32 smoke variants for tight comparison; whisper needs its cross cache
+# and has a test of its own
+ARCHS = [a for a in cfgs.ARCHS if cfgs.get_config(a).family != "encdec"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -3,7 +3,12 @@ updates, shifts or writes back the recurrent or conv state ``ssm_state``,
 which is what the benchmark's ``ssm_state_share.decode`` reads out of a
 device trace; and the names change nothing that runs."""
 import contextlib
+import os
+import pathlib
 import re
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +136,40 @@ def test_qwen2_step_carries_no_ssm_scope():
     names = {p for _, _, s in ops for p in s.split("/")}
     assert "attn" in names
     assert not names & {"ssm", "ssm_state"}
+
+
+def test_only_the_ssm_decode_step_imports_pallas():
+    """Pallas takes seconds to import, paid in set-up: lowering qwen2's
+    serve step leaves it unimported, and the SSM decode branch alone,
+    which calls the state-update kernel, brings it in."""
+    code = textwrap.dedent("""
+        import sys
+        import jax
+        import jax.numpy as jnp
+        from repro import configs as cfgs
+        from repro.models import model as model_mod
+        from repro.serve.step import make_serve_step
+
+        def lowered_with_pallas(arch):
+            cfg = cfgs.get_smoke_config(arch)
+            params = jax.eval_shape(
+                lambda k: model_mod.init_params(cfg, k), jax.random.PRNGKey(0))
+            cache = jax.eval_shape(lambda: model_mod.init_cache(cfg, 2, 8))
+            jax.jit(make_serve_step(cfg)).lower(
+                params, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32))
+            return "jax.experimental.pallas" in sys.modules
+
+        print(lowered_with_pallas("qwen2-0.5b"),
+              lowered_with_pallas("mamba2-2.7b"))
+    """)
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    path = os.pathsep.join(filter(None, [str(src),
+                                         os.environ.get("PYTHONPATH")]))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=path)
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == ["False", "True"], r.stdout
 
 
 @pytest.mark.parametrize("arch", ["mamba2-2.7b", "qwen2-0.5b"])
