@@ -31,10 +31,11 @@ def forward(params, batch, cfg: ModelConfig, attn_fn=None):
     return transformer.forward(params, batch, cfg, attn_fn=attn_fn)
 
 
-def decode_step(params, cache, tokens, cfg: ModelConfig, attn_fn=None):
+def decode_step(params, cache, tokens, cfg: ModelConfig, n=None,
+                attn_fn=None):
     """``attn_fn`` is taken for the serve step's signature and unused:
     attention against a cache has no fused kernel."""
-    return transformer.decode_step(params, cache, tokens, cfg)
+    return transformer.decode_step(params, cache, tokens, cfg, n=n)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int):

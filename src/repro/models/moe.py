@@ -36,8 +36,9 @@ def capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max((c + 7) // 8 * 8, 8)
 
 
-def moe_mlp(x, p, cfg: ModelConfig):
-    """x [T, d] -> [T, d] plus aux losses dict."""
+def moe_mlp(x, p, cfg: ModelConfig, valid=None):
+    """x [T, d] -> [T, d] plus aux losses dict.  ``valid`` [T]: the tokens
+    routed (all where None); the others take no slot and give zeros."""
     t, d = x.shape
     e, k = cfg.n_experts, cfg.moe_top_k
     c = capacity(cfg, t)
@@ -49,6 +50,9 @@ def moe_mlp(x, p, cfg: ModelConfig):
     gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
 
     flat_e = eidx.reshape(-1)                                 # [T*k]
+    if valid is not None:
+        # a pad's choices sort after every expert's and are dropped
+        flat_e = jnp.where(jnp.repeat(valid, k), flat_e, e)
     order = jnp.argsort(flat_e)                               # sort by expert
     sorted_e = flat_e[order]
     tok_idx = order // k
@@ -57,6 +61,8 @@ def moe_mlp(x, p, cfg: ModelConfig):
     starts = jnp.cumsum(counts) - counts
     pos_in_grp = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_e]
     keep = pos_in_grp < c
+    if valid is not None:
+        keep &= sorted_e < e
     dest = jnp.where(keep, sorted_e * c + pos_in_grp, e * c)  # drop row
 
     if cfg.moe_dispatch == "index":

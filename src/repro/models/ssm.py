@@ -40,14 +40,21 @@ def ssm_specs(cfg: ModelConfig) -> Dict[str, PSpec]:
     }
 
 
-def _causal_conv(x, w, b, state: Optional[jnp.ndarray] = None):
+def _causal_conv(x, w, b, state: Optional[jnp.ndarray] = None,
+                 n_valid=None):
     """Depthwise causal conv1d.  x [B,S,C], w [K,C].  With ``state``
-    ([B,K-1,C]) performs the streaming update and returns (y, new_state)."""
+    ([B,K-1,C]) performs the streaming update and returns (y, new_state):
+    the K-1 inputs that end at the ``n_valid``-th of x (the last where
+    None)."""
     k = w.shape[0]
     if state is not None:
         with jax.named_scope("ssm_state"):
             window = jnp.concatenate([state, x], axis=1)   # [B, K-1+S, C]
-            new_state = window[:, -(k - 1):]
+            if n_valid is None:
+                new_state = window[:, -(k - 1):]
+            else:
+                new_state = jax.lax.dynamic_slice_in_dim(window, n_valid,
+                                                         k - 1, axis=1)
     else:
         window = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
         new_state = None
@@ -73,7 +80,9 @@ def ssd_chunked_ref(x, dt, a, b_mat, c_mat, chunk: int,
     dt [B,S,H]    softplus'd step sizes
     a  [H]        negative decay rates
     b_mat, c_mat [B,S,N]
-    Returns (y [B,S,H,P], last_state [B,H,P,N]).
+    h0 [B,H,P,N]  the state before the first position (zero where None)
+    Returns (y [B,S,H,P], last_state [B,H,P,N]); the states are read out
+    in float32.
     """
     bsz, s, h, p = x.shape
     n = b_mat.shape[-1]
@@ -119,18 +128,21 @@ def ssd_chunked_ref(x, dt, a, b_mat, c_mat, chunk: int,
     # inter-chunk output
     state_decay = jnp.exp(da_cs)                          # [B,NC,L,H]
     y_off = jnp.einsum("bcln,bchpn,bclh->bclhp",
-                       cc, prev_states.astype(cc.dtype), state_decay)
+                       cc.astype(jnp.float32), prev_states, state_decay)
     y = (y_diag + y_off).reshape(bsz, s, h, p)
     return y, last
 
 
 @jax.named_scope("ssm")
 def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
-                conv_state=None):
-    """Full Mamba2 block.  ``state`` given -> decode mode (S small,
-    typically 1): ``state`` is the stacked per-layer state [L,B,H,P,N],
-    ``layer`` this block's index in it and ``conv_state`` this block's conv
-    window; returns (y, (state with the layer rewritten, conv_state)).
+                conv_state=None, n_valid=None):
+    """Full Mamba2 block.  ``state`` given -> decode mode: ``state`` is the
+    stacked per-layer state [L,B,H,P,N], ``layer`` this block's index in it
+    and ``conv_state`` this block's conv window; returns (y, (state with the
+    layer rewritten, conv_state)).  At S = 1 the state takes the O(1)
+    recurrence; at S > 1 (a prompt's launch) the chunked SSD starts from
+    it.  ``n_valid``: the valid positions, the first ones; the others get a
+    step of zero, so they neither decay nor feed the state.
 
     Named ``ssm``; in decode mode the ops that read or rewrite the state
     (the recurrence with its read-out, the conv window's shift) are named
@@ -144,7 +156,8 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
     xbc = z_x_bc_dt[..., d_in:2 * d_in + 2 * n]
     dt_raw = z_x_bc_dt[..., 2 * d_in + 2 * n:]
 
-    xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
+    xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state,
+                                 n_valid)
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(bsz, s, h, hd)
     b_mat = xbc[..., d_in:d_in + n]
@@ -154,7 +167,7 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
                          + p["dt_bias"][None, None, :])
     a = -jnp.exp(p["a_log"])
 
-    if state is not None:
+    if state is not None and s == 1:
         # imported here: Pallas takes seconds to import, which only the SSM
         # decode path should pay
         from repro.kernels import ops as kernel_ops
@@ -173,9 +186,21 @@ def ssm_forward(x, p, cfg: ModelConfig, *, state=None, layer=None,
         y = y.reshape(bsz, 1, d_in).astype(x.dtype)
         carry = (new_state, new_conv)
     else:
-        y4, last = ssd_chunked_ref(xs, dt, a, b_mat, c_mat, cfg.ssm_chunk)
+        h0 = None
+        if state is not None:
+            with jax.named_scope("ssm_state"):
+                h0 = jax.lax.dynamic_index_in_dim(state, layer,
+                                                  keepdims=False)
+        if n_valid is not None:
+            dt = jnp.where(jnp.arange(s)[None, :, None] < n_valid, dt, 0.0)
+        y4, last = ssd_chunked_ref(xs, dt, a, b_mat, c_mat,
+                                   min(cfg.ssm_chunk, s), h0=h0)
         y4 = y4 + p["d_skip"][None, None, :, None] * xs.astype(y4.dtype)
         y = y4.reshape(bsz, s, d_in).astype(x.dtype)
+        if state is not None:
+            with jax.named_scope("ssm_state"):
+                last = jax.lax.dynamic_update_index_in_dim(state, last,
+                                                           layer, 0)
         carry = (last, new_conv)
 
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"], cfg.norm_eps)

@@ -168,13 +168,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
 # ---------------------------------------------------------------------------
 def _attn_layer(x, lp, cfg, positions, *, window=None, mrope_sections=None,
                 attn_fn=None, kv=None, cache_pos=None, layer=None,
-                cross=None, causal=True):
+                cross=None, causal=True, n=None):
     """Self attention (MLA where ``cfg.mla``), then cross attention over
     ``cross=(k, v)`` where given, then the dense or MoE MLP, each behind
     its norm and residual.
 
     ``kv``: the stacked caches of every layer, written at ``cache_pos`` in
     layer ``layer``; ``attn_fn`` takes over attention without them.
+    ``n``: the valid positions of each row, the first ones; the MoE routes
+    only those, so pads take no expert's capacity.
     Returns (x, MoE aux or None, kv).
     """
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -200,7 +202,9 @@ def _attn_layer(x, lp, cfg, positions, *, window=None, mrope_sections=None,
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.n_experts:
         b, s, d = h.shape
-        m, aux = moe_mod.moe_mlp(h.reshape(b * s, d), lp["moe"], cfg)
+        m, aux = moe_mod.moe_mlp(
+            h.reshape(b * s, d), lp["moe"], cfg,
+            valid=None if n is None else jnp.tile(jnp.arange(s) < n, b))
         m = m.reshape(b, s, d)
         if cfg.moe_dense_residual:
             m = m + mlp(h, lp["mlp"], cfg)
@@ -212,16 +216,18 @@ def _attn_layer(x, lp, cfg, positions, *, window=None, mrope_sections=None,
     return x + m, aux, kv
 
 
-def _ssm_layer(x, lp, cfg, *, state=None, layer=None, conv=None):
+def _ssm_layer(x, lp, cfg, *, state=None, layer=None, conv=None, n=None):
     """The Mamba-2 mixer behind its norm and residual, named ``ssm``.
 
     ``state``: the stacked state [L,B,H,P,N], rewritten at layer ``layer``;
-    ``conv``: this layer's conv window.  Returns (x, (state, conv)).
+    ``conv``: this layer's conv window; ``n``: the valid positions, the
+    first ones, which alone advance them.  Returns (x, (state, conv)).
     """
     with jax.named_scope("ssm"):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         y, carry = ssm_mod.ssm_forward(h, lp["ssm"], cfg, state=state,
-                                       layer=layer, conv_state=conv)
+                                       layer=layer, conv_state=conv,
+                                       n_valid=n)
         return x + y, carry
 
 
@@ -304,20 +310,29 @@ def forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
 
 
 # ---------------------------------------------------------------------------
-# Decode (one new token against a cache).
+# Decode (new tokens against a cache).
 # ---------------------------------------------------------------------------
-def decode_step(params, cache, tokens, cfg: ModelConfig):
-    """tokens [B, 1] -> (logits [B,1,V], new cache)."""
-    bsz = tokens.shape[0]
+def decode_step(params, cache, tokens, cfg: ModelConfig, n=None):
+    """tokens [B, T] at positions ``pos .. pos+T-1`` -> (logits [B,1,V] at
+    the last valid position, new cache).
+
+    ``n``: how many of the T tokens are valid, given where T > 1; the cache
+    advances by ``n``.  Pad positions write keys past the valid ones, which
+    only pad queries see and later launches overwrite, and give the SSM a
+    step of zero, so they neither decay nor feed its state.  At T = 1 this
+    is the one-token decode step.
+    """
+    bsz, t = tokens.shape
     pos = cache["pos"]
     x = embed(tokens, params["embed"], cfg)
-    positions = jnp.full((bsz, 1), pos, jnp.int32)
+    positions = pos + jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None],
+                                       (bsz, t))
     flags = layer_flags(cfg)
     shared = params.get("shared_attn")
 
     # every cache rides in the scan's carry and is written in place at the
     # scanned layer's index: the caches that grow by a position (k/v, the
-    # MLA latent, the shared k/v) take a one-position write, the SSM state
+    # MLA latent, the shared k/v) take a T-position write, the SSM state
     # and conv window a whole layer's
     if cfg.family in ("ssm", "hybrid"):
         scanned = {"params": params["layers"],
@@ -336,7 +351,7 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
             # window's read and write-back here
             cv = jax.lax.dynamic_index_in_dim(conv, i, keepdims=False)
             x, (state, cv) = _ssm_layer(x, sc["params"], cfg, state=state,
-                                        layer=i, conv=cv)
+                                        layer=i, conv=cv, n=n)
             conv = jax.lax.dynamic_update_index_in_dim(conv, cv, i, 0)
             if cfg.family == "hybrid":
                 x, kv = _shared_block(x, shared, cfg, positions,
@@ -348,14 +363,14 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
         with jax.named_scope("ssm_state"):
             (x, state, conv, kv), _ = jax.lax.scan(
                 body, (x, cache["state"], cache["conv"], kv), scanned)
-        new_cache = dict(cache, pos=pos + 1, state=state, conv=conv)
+        new_cache = dict(cache, state=state, conv=conv)
         if cfg.family == "hybrid":
             new_cache.update(shared_k=kv["k"], shared_v=kv["v"])
     else:
         scanned = {"params": params["layers"], "window": flags["window"],
                    "layer": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
         names = ("latent", "k_rope") if cfg.mla else ("k", "v")
-        kv = {n: cache[n] for n in names}
+        kv = {name: cache[name] for name in names}
         if cfg.family == "encdec":
             scanned.update(cross_k=cache["cross_k"], cross_v=cache["cross_v"])
 
@@ -365,12 +380,16 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
                      else None)
             x, _, kv = _attn_layer(x, sc["params"], cfg, positions,
                                    window=sc["window"], kv=kv, cache_pos=pos,
-                                   layer=sc["layer"], cross=cross)
+                                   layer=sc["layer"], cross=cross, n=n)
             return (x, kv), None
 
         (x, kv), _ = jax.lax.scan(body, (x, kv), scanned)
-        new_cache = dict(cache, pos=pos + 1, **kv)
+        new_cache = dict(cache, **kv)
+    new_cache["pos"] = pos + (t if n is None else n)
 
+    # only the last valid position goes through the head
+    if n is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head(x, params["embed"], cfg)
     return logits, new_cache

@@ -1,7 +1,8 @@
 """The decode cache is updated in place: the jitted serve step donates it
-and aliases every leaf, a launch writes the new position and nothing else
-(an SSM rewrites every layer's state and conv window), and donation leaves
-greedy decoding's tokens as they were."""
+and aliases every leaf, at the decode launch's width and the prompt's, a
+launch writes the new position and nothing else (an SSM rewrites every
+layer's state and conv window), and donation leaves greedy decoding's
+tokens as they were."""
 import dataclasses
 
 import jax
@@ -13,8 +14,8 @@ from repro import configs as cfgs
 from repro.models import model as M
 from repro.models import transformer
 from repro.models.layers import CACHE_TILE, kv_pack
-from repro.serve.step import (greedy_generate, jitted_serve_step,
-                              make_serve_step)
+from repro.serve.step import (PREFILL_WIDTH, greedy_generate,
+                              jitted_serve_step, make_serve_step)
 
 B, P, NEW, MAX_SEQ = 2, 5, 4, 12
 # dense, MLA, hybrid, SSM
@@ -37,15 +38,18 @@ def _setup(arch, dtype=None):
 def test_jitted_serve_step_aliases_every_cache_leaf(arch):
     cfg, params = _setup(arch)
     cache = M.init_cache(cfg, B, MAX_SEQ)
-    tok = jnp.zeros((B, 1), jnp.int32)
-    mem = jitted_serve_step(cfg).lower(params, cache, tok).compile() \
-        .memory_analysis()
     cache_bytes = sum(x.nbytes for x in jax.tree.leaves(cache))
-    assert mem.alias_size_in_bytes >= cache_bytes - cache["pos"].nbytes, mem
-    # the plain step, which launch/serve.py counts, donates nothing
-    mem = jax.jit(make_serve_step(cfg)).lower(params, cache, tok).compile() \
-        .memory_analysis()
-    assert mem.alias_size_in_bytes == 0, mem
+    # a decode launch, and a prompt's with its count of valid tokens
+    for tokens in (jnp.zeros((B, 1), jnp.int32),
+                   (jnp.zeros((B, PREFILL_WIDTH), jnp.int32), jnp.int32(3))):
+        mem = jitted_serve_step(cfg).lower(params, cache, tokens).compile() \
+            .memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes - cache["pos"].nbytes, \
+            mem
+        # the plain step, which launch/serve.py counts, donates nothing
+        mem = jax.jit(make_serve_step(cfg)).lower(params, cache, tokens) \
+            .compile().memory_analysis()
+        assert mem.alias_size_in_bytes == 0, mem
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -84,12 +88,14 @@ def _greedy_matches_undonated_launches(arch):
                                 jnp.int32)
     out = greedy_generate(params, cfg, prompt, max_new=NEW, max_seq=MAX_SEQ)
 
+    # the launches greedy_generate makes, undonated: the prompt but its last
+    # token in one padded launch, then the decode launches
     step = jax.jit(make_serve_step(cfg))
     cache = M.init_cache(cfg, B, MAX_SEQ)
-    toks = [prompt[:, :1]]
-    for i in range(P - 1):
-        _, cache = step(params, cache, prompt[:, i:i + 1])
-        toks.append(prompt[:, i + 1:i + 2])
+    chunk = jnp.zeros((B, PREFILL_WIDTH), jnp.int32).at[:, :P - 1].set(
+        prompt[:, :P - 1])
+    _, cache = step(params, cache, (chunk, jnp.int32(P - 1)))
+    toks = [prompt]
     tok = prompt[:, -1:]
     for _ in range(NEW):
         tok, cache = step(params, cache, tok)

@@ -13,7 +13,7 @@ from jax.profiler import ProfileData
 from repro import configs as cfgs
 from repro.launch import compile_cache
 from repro.models import model as M
-from repro.serve.step import greedy_generate, make_serve_step
+from repro.serve.step import PREFILL_WIDTH, greedy_generate, make_serve_step
 from repro.spans import PREFIX
 
 P, NEW, MAX_SEQ = 5, 3, 12
@@ -52,7 +52,8 @@ def test_profiled_call_returns_the_same_tokens_and_its_spans(tiny, tmp_path):
     names = [ev.name.removeprefix(PREFIX) for ev in spans]
     assert names.count("serve.generate") == 1
     assert names.count("serve.init_cache") == 1
-    assert names.count("serve.prompt_step") == P - 1
+    # the prompt but its last token, in one launch of PREFILL_WIDTH
+    assert names.count("serve.prompt_step") == 1
     assert names.count("serve.decode_step") == NEW
     assert names.count("serve.concat") == 1
     (gen,) = [ev for ev in spans if ev.name == PREFIX + "serve.generate"]
@@ -62,7 +63,8 @@ def test_profiled_call_returns_the_same_tokens_and_its_spans(tiny, tmp_path):
     steps = sorted((ev for ev in spans if ev.name.endswith("_step")),
                    key=lambda ev: ev.start_ns)
     assert [ev.name.endswith("prompt_step") for ev in steps] == \
-        [True] * (P - 1) + [False] * NEW
+        [True] + [False] * NEW
+    assert dict(steps[0].stats) == {"tokens": P - 1, "width": PREFILL_WIDTH}
     assert gen.start_ns <= steps[0].start_ns
     assert steps[-1].end_ns <= gen.end_ns
 

@@ -174,17 +174,20 @@ def test_only_the_ssm_decode_step_imports_pallas():
 
 @pytest.mark.parametrize("arch", ["mamba2-2.7b", "qwen2-0.5b"])
 def test_scopes_change_no_arithmetic(arch, monkeypatch):
-    """The serve step lowers to the same module with every scope taken out,
-    and ``greedy_generate`` gives the same tokens bit for bit."""
+    """The serve step lowers to the same modules with every scope taken
+    out, at the decode launch's width and the prompt's, and
+    ``greedy_generate`` gives the same tokens bit for bit."""
     cfg = cfgs.get_smoke_config(arch)
     params = model_mod.init_params(cfg, jax.random.PRNGKey(1))
     prompt = jax.random.randint(jax.random.PRNGKey(2), (BATCH, 3), 0,
                                 cfg.vocab, jnp.int32)
     cache = model_mod.init_cache(cfg, BATCH, MAX_SEQ)
+    chunk = jnp.zeros((BATCH, step_mod.PREFILL_WIDTH), jnp.int32)
 
     def lowered():
-        return jax.jit(step_mod.make_serve_step(cfg)).lower(
-            params, cache, prompt[:, :1]).as_text()
+        step = jax.jit(step_mod.make_serve_step(cfg))
+        return [step.lower(params, cache, tokens).as_text()
+                for tokens in (prompt[:, :1], (chunk, jnp.int32(2)))]
 
     def generate():
         return np.asarray(step_mod.greedy_generate(params, cfg, prompt, 4,

@@ -8,6 +8,7 @@ loads the TPU library, which one process at a time may hold, so it must
 never happen while a module is imported or collected.
 """
 import dataclasses
+import math
 import os
 import re
 
@@ -112,18 +113,21 @@ def test_qwen2_train_step_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES, mem
 
 
-def _decode_step(one_chip, batch, max_seq, cfg=None):
-    """The full-width decode step ``serve/step.greedy_generate`` jits (cache
+def _decode_step(one_chip, batch, max_seq, cfg=None, width=1):
+    """The full-width serve step ``serve/step.greedy_generate`` jits (cache
     donated) for ``cfg`` (qwen2-0.5b unless given), compiled at ``batch`` x
-    ``max_seq``."""
+    ``max_seq`` for launches of ``width`` tokens (a prompt's, with its count
+    of valid tokens, where more than 1)."""
     cfg = cfg or cfgs.get_config("qwen2-0.5b")
     on_chip = lambda a: _sds(one_chip, a.shape, a.dtype)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: model_mod.init_params(cfg, k), jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_chip, jax.eval_shape(
         lambda: model_mod.init_cache(cfg, batch, max_seq)))
-    compiled = jitted_serve_step(cfg).lower(
-        params, cache, _sds(one_chip, (batch, 1), jnp.int32)).compile()
+    tokens = _sds(one_chip, (batch, width), jnp.int32)
+    if width > 1:
+        tokens = (tokens, _sds(one_chip, (), jnp.int32))
+    compiled = jitted_serve_step(cfg).lower(params, cache, tokens).compile()
     return compiled, cache
 
 
@@ -196,3 +200,45 @@ def test_zamba2_decode_step_updates_the_state_in_place(one_chip,
     copies = [line for line in compiled.as_text().splitlines()
               if re.search(r"= %s\S* copy(-start)?\(" % shape, line)]
     assert not copies, copies
+
+
+def _nbytes(sds) -> int:
+    return math.prod(sds.shape) * sds.dtype.itemsize
+
+
+def _no_copy(compiled, shape):
+    """No copy of an array of ``shape`` (the stacked cache) in ``compiled``."""
+    pat = r"= \w+\[%s\]\S* copy(-start)?\(" % ",".join(map(str, shape))
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(pat, line)]
+    assert not copies, copies
+
+
+def test_qwen2_prefill_step_writes_the_cache_in_place(one_chip):
+    """The prompt's launch at the benchmark cell's shape (128 requests of
+    PREFILL_WIDTH positions, a 1149-position cache) writes its positions
+    into the donated cache: every leaf aliased, no copy of the cache, and
+    no more temporaries than one of its stacked keys or values."""
+    compiled, cache = _decode_step(one_chip, 128, 1149,
+                                   width=step_mod.PREFILL_WIDTH)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+    assert mem.temp_size_in_bytes < _nbytes(cache["k"]), mem
+    _no_copy(compiled, cache["k"].shape)
+
+
+def test_mamba2_prefill_step_updates_the_state_in_place(one_chip,
+                                                        monkeypatch):
+    """The prompt's launch of mamba2-2.7b cut to 8 layers (128 requests of
+    PREFILL_WIDTH positions) runs the chunked SSD from each layer's state and
+    writes the final state back into the donated cache: no copy of the
+    stacked state, and fewer temporaries than its size."""
+    monkeypatch.setattr(ops, "_auto_interpret", lambda interpret: False)
+    monkeypatch.setattr(step_mod, "_SERVE_STEP_CACHE", {})
+    cfg = dataclasses.replace(cfgs.get_config("mamba2-2.7b"), n_layers=8)
+    compiled, cache = _decode_step(one_chip, 128, 1149, cfg,
+                                   width=step_mod.PREFILL_WIDTH)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20, mem
+    assert mem.temp_size_in_bytes < _nbytes(cache["state"]), mem
+    _no_copy(compiled, cache["state"].shape)
